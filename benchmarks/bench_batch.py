@@ -35,6 +35,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 
 from repro.core.hotset import build_hot_index
@@ -288,6 +290,7 @@ def sim_pipeline(fast: bool, out_path: str):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="small smoke configuration for CI (~30 s)")

@@ -50,6 +50,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 
 from benchmarks import common as C
@@ -138,6 +140,7 @@ def _pair(rows, storm, proto):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--out", default=os.path.join(

@@ -39,6 +39,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 
 def recovery_section(fast: bool, wal_out: str | None):
     from benchmarks import common as C
@@ -97,6 +99,7 @@ def sim_section(fast: bool):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: fewer txns, fewer sweep points")

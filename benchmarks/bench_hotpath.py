@@ -39,6 +39,8 @@ import warnings
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,6 +208,7 @@ def equivalence(txns, hi, loads, batch):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="small CI smoke (~30 s); still asserts "

@@ -46,6 +46,8 @@ os.environ.setdefault("XLA_FLAGS",
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 
 from repro.core.hotset import build_hot_index
@@ -156,6 +158,7 @@ def equivalence(sweep, traces, keys, n_txns, batch):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="small CI smoke; still asserts cross-N "

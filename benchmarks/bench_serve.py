@@ -58,6 +58,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.common.compile_cache import enable_compile_cache  # noqa: E402
+
 import numpy as np
 
 from benchmarks import common as C
@@ -221,6 +223,7 @@ def des_million(fast):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: 2k-txn functional points, 50k-arrival "

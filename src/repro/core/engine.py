@@ -85,14 +85,6 @@ from repro.core.packets import (ADD, ADDP, CADD, NOP, READ, WRITE,
                                 SwitchConfig, result_plane, shard_rows)
 
 
-def init_registers(cfg: SwitchConfig, values: Optional[np.ndarray] = None):
-    if values is None:
-        return jnp.zeros((cfg.n_stages, cfg.regs_per_stage), jnp.int32)
-    # always copy: the engine donates its register buffer to compiled
-    # calls, so aliasing a caller-held device array would invalidate it
-    return jnp.array(values, jnp.int32, copy=True)
-
-
 # ------------------------------------------------------------- serial ----
 
 def _serial_engine_impl(registers, op, stage, reg, val):
@@ -415,7 +407,9 @@ class SwitchEngine:
         # call) to one device of the mesh — the per-shard plane of a
         # ShardedSwitchEngine; None keeps the default-device behavior
         self._device = device
-        self.registers = self._put(init_registers(cfg, registers))
+        self.registers = self._put(
+            np.zeros((cfg.n_stages, cfg.regs_per_stage), np.int32)
+            if registers is None else registers)
         self.next_gid = 0
         self.dispatch_count = 0
         self.read_dispatch_count = 0    # READ-only gathers (no GID, no WAL)
@@ -431,8 +425,13 @@ class SwitchEngine:
         self._last_fut = None
         self._defer_futs = collections.deque()   # submitted, not yet run
 
-    def _put(self, x):
-        return x if self._device is None else jax.device_put(x, self._device)
+    def _put(self, host):
+        """One host -> device transfer straight onto this plane's device
+        (the default device when unpinned), never via another chip.
+        ``np.array`` copies first: staging buffers are recycled and
+        register values are caller-owned, and a device buffer (donated to
+        compiled calls) must never alias either."""
+        return jax.device_put(np.array(host, np.int32), self._device)
 
     # ------------------------------------------------ dispatch thread --
     def _submit(self, job, defer: bool):
@@ -564,9 +563,7 @@ class SwitchEngine:
         if mode == "pallas":
             def job():
                 from repro.kernels.switch_txn import ops as ktx
-                # jnp.array (copy=True): the staging buffer is recycled,
-                # so the device buffer must never alias host memory
-                fused = self._put(jnp.array(staged))
+                fused = self._put(staged)
                 regs, res, ok = ktx.switch_exec(self.registers, fused[0],
                                                 fused[1], fused[2],
                                                 fused[3])
@@ -578,7 +575,7 @@ class SwitchEngine:
             fn = _compiled_engine(mode, S, R, Bp, K, Mp, self._device)
 
             def job():
-                fused = self._put(jnp.array(staged))
+                fused = self._put(staged)
                 regs, res, ok, compact = fn(self.registers, fused)
                 self.registers = regs
                 return regs, res, ok, compact
@@ -618,15 +615,14 @@ class SwitchEngine:
         if mode == "pallas":
             def job():
                 from repro.kernels.switch_txn import ops as ktx
-                return ktx.gather_results(self.registers,
-                                          self._put(jnp.array(idx)))
+                return ktx.gather_results(self.registers, self._put(idx))
         else:
             fn = _compiled_reader(S, R, Mp, self._device)
 
             def job():
                 # reads self.registers AT EXECUTION time on the dispatch
                 # thread — FIFO chaining puts it after every earlier write
-                return fn(self.registers, self._put(jnp.array(idx)))
+                return fn(self.registers, self._put(idx))
 
         self.read_dispatch_count += 1
         out, fut = self._submit(job, defer)
@@ -651,7 +647,7 @@ class SwitchEngine:
         from repro.kernels.switch_txn import ops as ktx
         if (cap is None) == (k is None):
             raise ValueError("exactly one of cap/k")
-        idx = self._put(jnp.asarray(rp.flat_idx(self.cfg)))
+        idx = self._put(rp.flat_idx(self.cfg))
 
         def job():
             if k is not None:
@@ -675,17 +671,17 @@ class SwitchEngine:
     def restore(self, snap):
         self._join()
         regs, gid = snap
-        # init_registers copies: the register buffer is donated to later
-        # compiled calls, so the restored snapshot (a checkpoint the warm
-        # standby may restore from repeatedly) must never be aliased
-        self.registers = self._put(init_registers(self.cfg, regs))
+        # _put copies: the register buffer is donated to later compiled
+        # calls, so the restored snapshot (a checkpoint the warm standby
+        # may restore from repeatedly) must never be aliased
+        self.registers = self._put(regs)
         self.next_gid = gid
 
     def load_registers(self, values):
         """Replace the whole register file ([S, R] host array) — the bulk
         path migration/restore uses; copies, never aliases the input."""
         self._join()
-        self.registers = self._put(init_registers(self.cfg, values))
+        self.registers = self._put(values)
 
     def read_value(self, slot) -> int:
         """Read one register by placement slot ((switch, stage, reg) or

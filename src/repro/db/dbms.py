@@ -43,8 +43,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.engine import ShardedSwitchEngine, SwitchEngine, \
-    init_registers
+from repro.core.engine import ShardedSwitchEngine, SwitchEngine
 from repro.core.hotset import HotIndex
 from repro.core.packets import (ADD, ADDP, CADD, NOP, READ, WRITE,
                                 SwitchConfig, addp_unsafe_rows,

@@ -14,17 +14,11 @@ from repro.kernels.switch_txn.switch_txn import (result_gather_call,
 NOP = 0
 
 
-def _interpret_default():
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def switch_exec(registers, op, stage, reg, val, chunk=1024, interpret=None):
     """registers: [S, R] int32; op/stage/reg/val: [B, K].
 
     Returns (new_registers [S,R], results [B,K], ok [B,K] bool)."""
-    if interpret is None:
-        interpret = _interpret_default()
     S, R = registers.shape
     B, K = op.shape
     n = B * K
@@ -47,8 +41,6 @@ def gather_results(res, idx, chunk=1024, interpret=None):
 
     res: [B, K] int32; idx: [M] int32 flat row-major positions (clamped).
     Returns [M] int32."""
-    if interpret is None:
-        interpret = _interpret_default()
     m = idx.shape[0]
     return result_gather_call(res.reshape(-1), idx,
                               chunk=min(chunk, max(m, 1)),
@@ -68,8 +60,6 @@ def scan_prune(registers, idx, lo, hi, cap, chunk=1024, interpret=None):
     registers: [S, R] int32; idx: [M] int32 flat slot positions in key
     order.  Returns (vals [cap], pos [cap] positions into idx, agg [4]
     = count/sum/min/max over all matches)."""
-    if interpret is None:
-        interpret = _interpret_default()
     m = idx.shape[0]
     src = result_gather_call(registers.reshape(-1), idx,
                              chunk=min(chunk, max(m, 1)),
@@ -86,8 +76,6 @@ def scan_topk(registers, idx, lo, hi, k, chunk=1024, interpret=None):
     ``lax.top_k`` rule).  Returns (vals [k], pos [k] positions into idx,
     count of all matches); slots past ``count`` hold the int32-min
     sentinel.  Requires k <= len(idx) (callers clamp)."""
-    if interpret is None:
-        interpret = _interpret_default()
     m = idx.shape[0]
     src = result_gather_call(registers.reshape(-1), idx,
                              chunk=min(chunk, max(m, 1)),

@@ -225,7 +225,8 @@ def _fused_engine_impl(mode: str, Mp: int):
     """Wrap an engine impl to (a) consume the single fused [N_PLANES, Bp, K]
     staging buffer (one H2D transfer per group instead of four) and (b)
     emit the compacted device-only result rows alongside the full plane —
-    all inside ONE compiled dispatch."""
+    all inside ONE compiled dispatch.  The program is named
+    ``jit_run.<mode>``, so a profiler trace tells the engines apart."""
     impl = _ENGINE_IMPLS[mode]
 
     def run(registers, fused):
@@ -235,6 +236,7 @@ def _fused_engine_impl(mode: str, Mp: int):
         compact = jnp.take(res.reshape(-1), idx, mode="clip")
         return regs, res, ok, compact
 
+    run.__name__ = run.__qualname__ = f"run.{mode}"
     return run
 
 
@@ -343,15 +345,22 @@ class PendingBatch:
     working unchanged."""
 
     __slots__ = ("res", "ok", "compact", "gids", "B", "K", "base", "idx",
-                 "mode", "_fut", "_res_np")
+                 "mode", "engine", "_fut", "_res_np")
 
     def __init__(self, res, ok, compact, gids, B, K, base, idx,
-                 mode="auto", fut=None):
+                 mode="auto", fut=None, engine=None):
         self.res, self.ok, self.compact = res, ok, compact
         self.gids, self.B, self.K = gids, B, K
         self.base, self.idx, self.mode = base, idx, mode
+        self.engine = engine        # counts the D2H bytes, when given
         self._fut = fut
         self._res_np = None
+
+    def _to_host(self, arr) -> np.ndarray:
+        out = np.asarray(arr)
+        if self.engine is not None:
+            self.engine.d2h_bytes += out.nbytes
+        return out
 
     def _resolve(self):
         """Join the dispatch thread's future (deferred handles only)."""
@@ -367,13 +376,20 @@ class PendingBatch:
             out = self.base.copy()
             if len(self.idx):
                 out.reshape(-1)[self.idx] = \
-                    np.asarray(self.compact)[:len(self.idx)]
+                    self._to_host(self.compact)[:len(self.idx)]
             self._res_np = out
         return self._res_np
 
     def ok_np(self) -> np.ndarray:
         self._resolve()
-        return np.asarray(self.ok)[:self.B]
+        return self._to_host(self.ok)[:self.B]
+
+    def release(self):
+        """Free the device arrays once ``results_np()`` has copied what the
+        caller needs: the group's buffers go now, not whenever the last
+        reference to the handle dies."""
+        self._resolve()
+        self.res = self.ok = self.compact = None
 
     def block(self):
         """Barrier: wait for this dispatch's device work to finish."""
@@ -397,7 +413,9 @@ class SwitchEngine:
 
     ``dispatch_count`` counts device dispatches (compiled-engine calls) —
     the batched DBMS hot path commits a whole group of hot transactions in
-    exactly one."""
+    exactly one.  ``h2d_bytes`` counts the fused staging buffers the write
+    dispatches send to the device, ``d2h_bytes`` the result arrays their
+    handles copy back."""
 
     def __init__(self, cfg: SwitchConfig, registers=None,
                  stager_pool: int = 4, async_dispatch: bool = False,
@@ -413,6 +431,8 @@ class SwitchEngine:
         self.next_gid = 0
         self.dispatch_count = 0
         self.read_dispatch_count = 0    # READ-only gathers (no GID, no WAL)
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
         # reusable host staging buffers (one fused H2D per dispatch); the
         # pool must stay deeper than the caller's async in-flight window
         self._stager = PacketStager(pool=stager_pool)
@@ -581,13 +601,15 @@ class SwitchEngine:
                 return regs, res, ok, compact
 
         self.dispatch_count += 1
+        self.h2d_bytes += staged.nbytes
         self.next_gid = max(self.next_gid, int(gids[-1]) + 1)
         out, fut = self._submit(job, defer)
         if fut is not None:
             return PendingBatch(None, None, None, gids, B, K, base, idx,
-                                mode, fut=fut)
+                                mode, fut=fut, engine=self)
         _, res, ok, compact = out
-        return PendingBatch(res, ok, compact, gids, B, K, base, idx, mode)
+        return PendingBatch(res, ok, compact, gids, B, K, base, idx, mode,
+                            engine=self)
 
     def execute_reads(self, rp: ReadPacket, mode: str = "auto",
                       defer: bool = False) -> PendingRead:
@@ -746,6 +768,14 @@ class ShardedSwitchEngine:
     @property
     def read_dispatch_count(self) -> int:
         return sum(p.read_dispatch_count for p in self.planes)
+
+    @property
+    def h2d_bytes(self) -> int:
+        return sum(p.h2d_bytes for p in self.planes)
+
+    @property
+    def d2h_bytes(self) -> int:
+        return sum(p.d2h_bytes for p in self.planes)
 
     @property
     def registers(self):
@@ -1067,6 +1097,12 @@ class _MergedBatch:
     def ok_np(self) -> np.ndarray:
         self._materialize()
         return self._ok_np
+
+    def release(self):
+        self._materialize()
+        for _, pb, _, _ in self._parts:
+            if pb is not None:
+                pb.release()
 
     def block(self):
         for _, pb, _, _ in self._parts:

@@ -66,7 +66,9 @@ NO_WAIT, WAIT_DIE = "NO_WAIT", "WAIT_DIE"
 
 def _span(tr, name):
     """Trace span or no-op: call sites stay branch-free when tracing is
-    off or this txn wasn't sampled."""
+    off or this txn wasn't sampled.  Per-txn call sites read ``tr.clock``
+    twice and ``tr.add_span`` instead, which costs less.  Spans never
+    nest: each instant of a call is in at most one span."""
     return tr.span(name) if tr is not None else contextlib.nullcontext()
 
 # base tid for Cluster.load() fixture writes — disjoint from client txns
@@ -260,6 +262,12 @@ class Cluster:
             self.metrics = registry if registry is not None \
                 else MetricsRegistry()
             self.tracer = tracer if tracer is not None else Tracer()
+            if tracer is not None:
+                # a caller's tracer also times WAL appends; the default
+                # tracer leaves them untimed, so no clock is read there
+                for n in self.nodes:
+                    if isinstance(n.wal, SegmentedWAL):
+                        n.wal.clock = tracer.clock
             self.stats: collections.Counter = StatsCounter(self.metrics,
                                                            stat_metric)
         else:
@@ -449,7 +457,7 @@ class Cluster:
             self.stats["hot"] += 1
             out = self._run_hot(txn, tr=tr)
         else:
-            out = self._run_with_retries(txn, kind, max_retries)
+            out = self._run_traced(txn, kind, max_retries, tr)
         if self.metrics is not None:
             self.metrics.histogram(
                 H_TXN_LATENCY, help="admission-to-result txn latency",
@@ -471,11 +479,16 @@ class Cluster:
         with _span(tr, "packet-build"):
             pkt, meta = build_packets([txn], self.hot_index, self.switch_cfg)
         self._validate_mode(meta)
+        t0 = tr.clock() if tr is not None else 0.0
         home.log("switch_send", txn.tid, ops=list(txn.ops))
+        if tr is not None:
+            tr.add_span("wal-send", t0, tr.clock())
         with _span(tr, "dispatch"):
             pb = self.switch.execute_batch(pkt, meta, mode=self.switch_mode)
         with _span(tr, "drain"):
             res = pb.results_np()
+            pb.release()
+        t0 = tr.clock() if tr is not None else 0.0
         home.log("switch_result", txn.tid, gid=int(pb.gids[0]),
                  results=res[0, :len(txn.ops)].tolist())
         self.stats["commits"] += 1
@@ -485,6 +498,8 @@ class Cluster:
         out = [0] * len(txn.ops)
         for slot in range(len(txn.ops)):
             out[order[0, slot]] = int(res[0, slot])
+        if tr is not None:
+            tr.add_span("scatter", t0, tr.clock())
         self._note_sends(1)
         return out
 
@@ -551,7 +566,7 @@ class Cluster:
                 # AND sync every outstanding handle (consistency point)
                 self._flush_hot_group(pending, results, tr=tr)
                 self.drain()
-            results[i] = self._run_with_retries(txn, kind, max_retries)
+            results[i] = self._run_traced(txn, kind, max_retries, tr)
         self._flush_hot_group(pending, results, tr=tr)
         if self.metrics is not None:
             # admission -> dispatch for the async path (results still lazy
@@ -563,7 +578,20 @@ class Cluster:
             return LazyResults(self, results)
         return results
 
-    def _run_with_retries(self, txn: Txn, kind: str, max_retries: int):
+    def _run_traced(self, txn: Txn, kind: str, max_retries: int, tr):
+        """``_run_with_retries`` under the trace ``tr``: a cold txn's whole
+        2PL/2PC run, retries and aborts included, is one ``cold`` span; a
+        warm txn spans its cold parts and its switch sub-txn itself
+        (``_run_warm``)."""
+        if tr is None or kind != "cold":
+            return self._run_with_retries(txn, kind, max_retries, tr)
+        t0 = tr.clock()
+        out = self._run_with_retries(txn, kind, max_retries)
+        tr.add_span("cold", t0, tr.clock())
+        return out
+
+    def _run_with_retries(self, txn: Txn, kind: str, max_retries: int,
+                          tr=None):
         """Cold/warm execution under the retry policy.  Attempts are
         budgeted by ``self.retry_policy`` — or, when none is set, a
         default ``RetryPolicy(max_retries=max_retries)`` whose schedule
@@ -573,7 +601,11 @@ class Cluster:
         undrained async slot) after one ``gave_up`` bump.  Per-class
         attempt counts land in the ``txn_retries`` histogram; ops burnt
         by eventually-aborted attempts in ``stats["wasted_ops"]``."""
-        fn = self._run_cold if kind == "cold" else self._run_warm
+        if kind == "cold":
+            fn = self._run_cold
+        else:
+            def fn(t):
+                return self._run_warm(t, tr)
         policy = self.retry_policy if self.retry_policy is not None \
             else RetryPolicy(max_retries=max_retries)
         det = self.detector
@@ -651,11 +683,13 @@ class Cluster:
         keep the single-dispatch, validate-as-a-unit contract."""
         if not pending:
             return
-        pkts, meta = build_packets([t for _, t in pending], self.hot_index,
-                                   self.switch_cfg)
-        if self.switch_mode == "auto" and meta["addp_unsafe"] \
-                and len(pending) > 1:
-            unsafe = addp_unsafe_rows(pkts)
+        with _span(tr, "packet-build"):
+            pkts, meta = build_packets([t for _, t in pending],
+                                       self.hot_index, self.switch_cfg)
+            split = self.switch_mode == "auto" and meta["addp_unsafe"] \
+                and len(pending) > 1
+            unsafe = addp_unsafe_rows(pkts) if split else None
+        if split:
             lo = 0
             for hi in range(1, len(pending) + 1):
                 if hi == len(pending) or unsafe[hi] != unsafe[lo]:
@@ -684,9 +718,12 @@ class Cluster:
             pkts, meta = prebuilt or build_packets(group, self.hot_index,
                                                    self.switch_cfg)
         self._validate_mode(meta)
+        t0 = tr.clock() if tr is not None else 0.0
         for t in group:
             # list(t.ops): ops tuples are immutable, no need to repack
             self.nodes[t.home].log("switch_send", t.tid, ops=list(t.ops))
+        if tr is not None:
+            tr.add_span("wal-send", t0, tr.clock())
         # Fig-9 window: sends are logged (committed-on-send) but the device
         # has not executed — a crash here leaves the whole group as
         # unknown-GID entries that recovery must replay
@@ -748,6 +785,10 @@ class Cluster:
         instead of a per-op Python loop."""
         with _span(tr, "drain"):
             res = pb.results_np()                   # [B, K] host plane
+            # freeing device buffers lets the async dispatch thread take
+            # the GIL: do it inside the span, not where the handle dies
+            pb.release()
+        t0 = tr.clock() if tr is not None else 0.0
         B, K = res.shape
         order = meta["order"]
         n_ops = meta["n_ops"]
@@ -761,6 +802,8 @@ class Cluster:
                                    gid=int(pb.gids[b]),
                                    results=res[b, :n].tolist())
             results[i] = outs[b, :n].tolist()
+        if tr is not None:
+            tr.add_span("scatter", t0, tr.clock())
 
     def _to_packet(self, txn: Txn):
         """Build the switch packet for ONE txn: ``build_packets`` at B=1,
@@ -834,7 +877,7 @@ class Cluster:
 
     # warm: cold part made abort-proof first, then the switch sub-txn
     # (paper §6.2, Fig 8/10)
-    def _run_warm(self, txn: Txn):
+    def _run_warm(self, txn: Txn, tr=None):
         self._ts += 1
         hot_keys = {k for k in txn.keys() if self.hot_index.is_hot(k)}
         cold_ops = [(i, (o, k, v)) for i, (o, k, v) in enumerate(txn.ops)
@@ -853,13 +896,21 @@ class Cluster:
             _, meta = build_packets([hot_txn], self.hot_index,
                                     self.switch_cfg)
             self._validate_mode(meta)
-        cold_res = self._exec_on_nodes(cold_txn, ts=self._ts)
+        t0 = tr.clock() if tr is not None else 0.0
+        try:
+            cold_res = self._exec_on_nodes(cold_txn, ts=self._ts)
+        finally:            # an aborted attempt's work is cold work too
+            if tr is not None:
+                tr.add_span("cold", t0, tr.clock())
         # cold part can no longer abort -> send switch sub-txn
-        hot_res = self._run_hot(hot_txn)
+        hot_res = self._run_hot(hot_txn, tr=tr)
         # commit cold part everywhere (2PC decision broadcast)
+        t0 = tr.clock() if tr is not None else 0.0
         for p in {node_of(k) for k in cold_txn.keys()}:
             self.nodes[p].log("commit", txn.tid)
             self.nodes[p].release_all(txn.tid)
+        if tr is not None:
+            tr.add_span("cold", t0, tr.clock())
         results = [0] * len(txn.ops)
         for (i, _), r in zip(cold_ops, cold_res):
             results[i] = r
@@ -990,6 +1041,15 @@ class Cluster:
                 out.append(dict(node=n.id, ok=True, records=len(n.wal),
                                 segments=0, sealed=0))
         return out
+
+    def wal_counters(self) -> dict:
+        """WAL appends summed over the nodes: records, bytes of their
+        canonical encoding, and seconds in ``append`` (timed only when
+        the cluster was handed a tracer)."""
+        wals = [n.wal for n in self.nodes if isinstance(n.wal, SegmentedWAL)]
+        return dict(records=sum(w.records_appended for w in wals),
+                    bytes=sum(w.bytes_appended for w in wals),
+                    append_s=sum(w.append_s for w in wals))
 
     # --------------------------------------------------------- telemetry --
     def export_metrics(self, fmt: str = "prometheus"):

@@ -74,9 +74,12 @@ def _canon(obj) -> bytes:
                       default=_jsonable).encode()
 
 
+def _chain_hash(prev: str, body: bytes) -> str:
+    return hashlib.sha256(prev.encode() + body).hexdigest()
+
+
 def record_hash(prev: str, lsn: int, kind: str, tid: int, payload: dict) -> str:
-    return hashlib.sha256(
-        prev.encode() + _canon([lsn, kind, tid, payload])).hexdigest()
+    return _chain_hash(prev, _canon([lsn, kind, tid, payload]))
 
 
 @dataclass
@@ -101,7 +104,11 @@ class SegmentMeta:
 
 
 class SegmentedWAL:
-    """Segmented append-only hash-chained log (see module docstring)."""
+    """Segmented append-only hash-chained log (see module docstring).
+
+    Counters: ``records_appended`` and ``bytes_appended`` (the canonical
+    encoding each record's hash covers) on every append; ``append_s``, the
+    time spent in ``append``, only once an owner sets ``clock``."""
 
     def __init__(self, segment_size: int = DEFAULT_SEGMENT_SIZE):
         if segment_size < 1:
@@ -109,9 +116,14 @@ class SegmentedWAL:
         self.segment_size = int(segment_size)
         self._records: List[WALRecord] = []
         self._segments: List[SegmentMeta] = [SegmentMeta(0, 0)]
+        self.clock = None
+        self.records_appended = 0
+        self.bytes_appended = 0
+        self.append_s = 0.0
 
     # ------------------------------------------------------------ append
     def append(self, kind: str, tid: int, payload: dict) -> WALRecord:
+        t0 = self.clock() if self.clock is not None else 0.0
         if self._records:
             prev, lsn = self._records[-1].hash, self._records[-1].lsn + 1
         else:
@@ -122,10 +134,15 @@ class SegmentedWAL:
             seg.seal_hash = self._records[-1].hash
             seg = SegmentMeta(seg.index + 1, lsn)
             self._segments.append(seg)
-        rec = WALRecord(lsn, kind, int(tid), payload, prev,
-                        record_hash(prev, lsn, kind, int(tid), payload))
+        tid = int(tid)
+        body = _canon([lsn, kind, tid, payload])
+        rec = WALRecord(lsn, kind, tid, payload, prev, _chain_hash(prev, body))
         self._records.append(rec)
         seg.count += 1
+        self.records_appended += 1
+        self.bytes_appended += len(body)
+        if self.clock is not None:
+            self.append_s += self.clock() - t0
         return rec
 
     # ------------------------------------------------------- list surface

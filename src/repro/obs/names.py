@@ -56,7 +56,8 @@ SIM_ALIASES = {
 }
 
 # Span vocabularies (trace point names, in causal order).
-FUNCTIONAL_SPANS = ("classify", "packet-build", "dispatch", "drain")
+FUNCTIONAL_SPANS = ("classify", "cold", "packet-build", "wal-send",
+                    "dispatch", "drain", "scatter")
 SIM_SPANS = ("admission", "batcher-join", "switch-service", "commit")
 
 # Shared histogram / gauge names used by both instrumented layers.

@@ -10,12 +10,16 @@ million-arrival run costs O(capacity) memory.
 Determinism contract (pinned by tests/test_obs.py): two identical runs
 produce identical sequences of (trace label, span names, depths); on the DES
 side the timestamps are identical too, because they are sim time.
+
+``GcMeter`` times the Python collector's pauses, which no span can hold:
+a collection stops whatever span is open.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import time
 
 
@@ -37,27 +41,28 @@ class Span:
 
 
 class Trace:
-    __slots__ = ("label", "spans", "_stack", "_clock")
+    __slots__ = ("label", "spans", "_stack", "clock")
 
     def __init__(self, label, clock=time.perf_counter):
         self.label = label
         self.spans = []
         self._stack = []
-        self._clock = clock
+        self.clock = clock
 
     @contextlib.contextmanager
     def span(self, name):
-        s = Span(name, self._clock(), depth=len(self._stack))
+        s = Span(name, self.clock(), depth=len(self._stack))
         self.spans.append(s)
         self._stack.append(s)
         try:
             yield s
         finally:
             self._stack.pop()
-            s.t1 = self._clock()
+            s.t1 = self.clock()
 
     def add_span(self, name, t0, t1, depth=0):
-        """Explicit-timestamp form (DES side: t0/t1 are sim time)."""
+        """Explicit-timestamp form: DES sim time, or two ``clock()`` reads
+        on a hot path where the context manager costs too much."""
         self.spans.append(Span(name, t0, t1, depth))
 
     def names(self):
@@ -101,3 +106,42 @@ class Tracer:
         self.traces.clear()
         self.started = 0
         self._n = 0
+
+
+class GcMeter:
+    """Collector pauses between ``start()`` and ``stop()``, from
+    ``gc.callbacks``: every collection and its pause, and the full
+    (generation 2) collections among them.  ``stop()`` takes the callback
+    off again, so the meter leaves ``gc.callbacks`` as it found it."""
+
+    def __init__(self):
+        self.collections = 0
+        self.full_collections = 0
+        self.pause_s = 0.0
+        self._t0 = None
+
+    def _on(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.pause_s += time.perf_counter() - self._t0
+            self._t0 = None
+            self.collections += 1
+            self.full_collections += info["generation"] == 2
+
+    def start(self):
+        if self._on not in gc.callbacks:
+            gc.callbacks.append(self._on)
+        return self
+
+    def stop(self):
+        if self._on in gc.callbacks:
+            gc.callbacks.remove(self._on)
+        self._t0 = None
+        return self
+
+    def __enter__(self):
+        return self.start()
+
+    def __exit__(self, *exc):
+        self.stop()

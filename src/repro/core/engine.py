@@ -7,8 +7,10 @@ the serial order still equals admission order (§5.2).
 
 Two functional execution paths produce that serial-equivalent result:
 
-  serial  — lax.scan over the flattened instruction stream.  The oracle.
-            Handles every opcode including CADD (constrained write).
+  serial  — a sequential loop over the flattened instruction stream, run
+            on the batch's working set (its distinct slots), never the
+            register plane: a Pallas kernel on scalars in SMEM.  Handles
+            every opcode including CADD (constrained write).
 
   affine  — the TPU-native adaptation: every {READ, WRITE, ADD} op is an
             affine map v' = a*v + c; affine maps compose associatively, so a
@@ -21,7 +23,7 @@ Two functional execution paths produce that serial-equivalent result:
 
 A Pallas kernel (kernels/switch_txn) implements the serial-chunk engine
 with VMEM-resident registers — the literal switch-pipeline analogue — and
-is validated against the serial oracle in tests.
+is validated against the serial engine in tests.
 
 Every executed transaction gets a globally-unique ID (GID) reflecting the
 serial order; GIDs drive WAL recovery in repro.db (paper §6.1).
@@ -83,42 +85,67 @@ import numpy as np
 from repro.core.packets import (ADD, ADDP, CADD, NOP, READ, WRITE,
                                 N_PLANES, PacketStager, ReadPacket,
                                 SwitchConfig, result_plane, shard_rows)
+from repro.kernels.switch_txn.switch_txn import serial_ws_call
 
 
 # ------------------------------------------------------------- serial ----
 
+# instructions per serial kernel call: the call's seven [n] int32 arrays
+# (224 KiB) stay well inside a v5e core's 1 MiB of SMEM
+_SERIAL_CALL_INSTRS = 8192
+
+
 def _serial_engine_impl(registers, op, stage, reg, val):
-    """Oracle: sequential execution of the [B, K] instruction stream in
+    """Sequential execution of the [B, K] instruction stream in
     (txn, instr) order.  Handles every opcode; ADDP resolves the result of
-    an earlier instruction of the same txn."""
+    an earlier instruction of the same txn.
+
+    The loop runs on the batch's working set, never the register plane
+    (``_serial_rows``).  A batch larger than one kernel call's SMEM
+    budget runs as consecutive row blocks, each seeing the last's
+    writes."""
     S, R = registers.shape
     B, K = op.shape
+    rows = max(1, min(B, _SERIAL_CALL_INSTRS // K))
     flat = registers.reshape(-1)
-    g = (stage * R + reg).reshape(-1)
+    res, ok = [], []
+    for lo in range(0, B, rows):
+        blk = slice(lo, lo + rows)
+        flat, r, o = _serial_rows(flat, op[blk], stage[blk] * R + reg[blk],
+                                  val[blk])
+        res.append(r)
+        ok.append(o)
+    return (flat.reshape(S, R), jnp.concatenate(res).reshape(B, K),
+            jnp.concatenate(ok).reshape(B, K).astype(bool))
 
-    def step(carry, x):
-        regs, results = carry       # results: [B, K] accumulated
-        o, gi, v, b, k = x
-        cur = regs[gi]
-        prev = results[b, jnp.clip(v, 0, K - 1)]   # ADDP source result
-        addend = jnp.where(o == ADDP, prev, v)
-        post = cur + addend
-        cadd_ok = post >= 0
-        new = jnp.where(o == WRITE, v,
-              jnp.where((o == ADD) | (o == ADDP), post,
-              jnp.where((o == CADD) & cadd_ok, post, cur)))
-        res = jnp.where(o == READ, cur, jnp.where(o == NOP, 0, new))
-        ok = jnp.where(o == CADD, cadd_ok, True)
-        regs = regs.at[gi].set(jnp.where(o == NOP, cur, new))
-        results = results.at[b, k].set(res)
-        return (regs, results), ok
 
-    bb = jnp.repeat(jnp.arange(B), K)
-    kk = jnp.tile(jnp.arange(K), B)
-    (flat, results), ok = jax.lax.scan(
-        step, (flat, jnp.zeros((B, K), jnp.int32)),
-        (op.reshape(-1), g, val.reshape(-1), bb, kk))
-    return flat.reshape(S, R), results, ok.reshape(B, K)
+def _serial_rows(flat, op, g, val):
+    """Run the [b, K] rows ``op``/``val`` on slots ``g`` of the flat
+    register file: each slot is renamed to its rank among the rows'
+    distinct slots (a stable sort, segment starts and a cumsum, as in
+    ``_affine_engine_impl``), those slots are gathered into an [N]
+    working set, ``serial_ws_call`` steps through the instructions on it
+    and the working set is scattered back.  The kernel holds the
+    streams, the working set, the results and the flags in SMEM and steps
+    on the scalar unit (an XLA loop's step cost ~12.5 us on a TPU v5e,
+    whatever it carried).  Returns (flat, results [N], ok [N] int32)."""
+    n_slots = flat.shape[0]
+    K = op.shape[1]
+    N = op.size
+    g = g.reshape(-1)
+    order = jnp.argsort(g, stable=True)
+    gs = g[order]
+    seg_start = jnp.concatenate([jnp.ones((1,), bool), gs[1:] != gs[:-1]])
+    rank = (jnp.cumsum(seg_start) - 1).astype(jnp.int32)
+    local = jnp.zeros((N,), jnp.int32).at[order].set(rank)
+    # the distinct slot of each rank; ranks past the last point off the
+    # plane, so the final scatter drops them
+    slots = jnp.full((N,), n_slots, jnp.int32).at[
+        jnp.where(seg_start, rank, N)].set(gs, mode="drop")
+    ws = jnp.take(flat, slots, mode="clip")
+    ws, res, ok = serial_ws_call(op.reshape(-1), local, val.reshape(-1), ws,
+                                 k=K)
+    return flat.at[slots].set(ws, mode="drop"), res, ok
 
 
 def _staged_engine_impl(registers, op, stage, reg, val):
@@ -415,7 +442,9 @@ class SwitchEngine:
     the batched DBMS hot path commits a whole group of hot transactions in
     exactly one.  ``h2d_bytes`` counts the fused staging buffers the write
     dispatches send to the device, ``d2h_bytes`` the result arrays their
-    handles copy back."""
+    handles copy back.  ``serial_steps`` counts the serial engine's loop
+    steps (``Bp * K`` per dispatch, NOP padding included) and
+    ``serial_instrs`` the non-NOP instructions among them."""
 
     def __init__(self, cfg: SwitchConfig, registers=None,
                  stager_pool: int = 4, async_dispatch: bool = False,
@@ -433,6 +462,8 @@ class SwitchEngine:
         self.read_dispatch_count = 0    # READ-only gathers (no GID, no WAL)
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        self.serial_steps = 0
+        self.serial_instrs = 0
         # reusable host staging buffers (one fused H2D per dispatch); the
         # pool must stay deeper than the caller's async in-flight window
         self._stager = PacketStager(pool=stager_pool)
@@ -602,6 +633,9 @@ class SwitchEngine:
 
         self.dispatch_count += 1
         self.h2d_bytes += staged.nbytes
+        if mode == "serial":
+            self.serial_steps += Bp * K
+            self.serial_instrs += int(np.count_nonzero(op_np))
         self.next_gid = max(self.next_gid, int(gids[-1]) + 1)
         out, fut = self._submit(job, defer)
         if fut is not None:
@@ -776,6 +810,14 @@ class ShardedSwitchEngine:
     @property
     def d2h_bytes(self) -> int:
         return sum(p.d2h_bytes for p in self.planes)
+
+    @property
+    def serial_steps(self) -> int:
+        return sum(p.serial_steps for p in self.planes)
+
+    @property
+    def serial_instrs(self) -> int:
+        return sum(p.serial_instrs for p in self.planes)
 
     @property
     def registers(self):

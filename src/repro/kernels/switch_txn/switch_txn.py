@@ -23,8 +23,11 @@ Hardware mapping (Tofino -> TPU):
 
 The gather and the scan-prune kernels use the same two layouts: index and
 value streams in SMEM, anything addressed at random in ``[rows, 128]``
-VMEM.  VMEM use is the register file (5 MiB at the default 20 x 65,536
-configuration) plus the compacted scan output; ``_vmem_limit`` sets the
+VMEM.  The serial engine's loop (``serial_ws_call``) is all SMEM: it runs
+on the batch's working set, one word per distinct slot (``core/engine``
+gathers it from the register file and scatters it back), so every step
+is scalar loads and stores.  VMEM use is the register file (5 MiB at the
+default 20 x 65,536 configuration) plus the compacted scan output; ``_vmem_limit`` sets the
 compiler's scoped VMEM limit from those sizes.
 
 This is the faithful-execution path; the affine-scan engine (core/engine)
@@ -39,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NOP, READ, WRITE, ADD, CADD = 0, 1, 2, 3, 4
+NOP, READ, WRITE, ADD, CADD, ADDP = 0, 1, 2, 3, 4, 5
 
 LANES = 128
 _TILE = 8 * LANES                 # one (8, 128) int32 tile
@@ -170,6 +173,59 @@ def switch_txn_call(registers_flat, op, g, val, *, chunk=1024,
         interpret=_resolve(interpret),
     )(op, g, val, regs)
     return regs.reshape(-1)[:n_slots], res[:n], ok[:n]
+
+
+# -------------------------------------------- serial working-set kernel --
+
+def _serial_ws_kernel(op_ref, li_ref, val_ref, ws_in_ref, ws_ref, res_ref,
+                      ok_ref, *, n, k):
+    """The serial engine's loop: all of it in SMEM, on scalars.  ``ws``
+    is the batch's working set (one word per distinct slot), ``li`` each
+    instruction's index into it; instruction ``i`` is op ``i % k`` of txn
+    ``i // k``, so an ADDP's source result sits in the same row of
+    ``res``."""
+    def init(i, _):
+        ws_ref[i] = ws_in_ref[i]
+        res_ref[i] = 0
+        return ()
+
+    jax.lax.fori_loop(0, n, init, ())
+
+    def body(i, _):
+        o = op_ref[i]
+        li = li_ref[i]
+        v = val_ref[i]
+        cur = ws_ref[li]
+        prev = res_ref[i - i % k + jnp.clip(v, 0, k - 1)]
+        post = cur + jnp.where(o == ADDP, prev, v)
+        cadd_ok = post >= 0
+        # READ and NOP leave new == cur, so the store is a no-op there
+        new = jnp.where(o == WRITE, v,
+              jnp.where((o == ADD) | (o == ADDP), post,
+              jnp.where((o == CADD) & cadd_ok, post, cur)))
+        ws_ref[li] = new
+        res_ref[i] = jnp.where(o == READ, cur, jnp.where(o == NOP, 0, new))
+        ok_ref[i] = jnp.where(o == CADD, cadd_ok, True).astype(jnp.int32)
+        return ()
+
+    jax.lax.fori_loop(0, n, body, ())
+
+
+def serial_ws_call(op, li, val, ws, *, k, interpret=None):
+    """Sequential execution of a flattened [n = B*k] instruction stream
+    over a working set: ``op``/``val`` are the instructions, ``li[i]``
+    indexes instruction ``i``'s slot in ``ws`` (every array [n] int32).
+
+    Returns (ws after the batch [n], results [n], ok [n] int32)."""
+    n = op.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        functools.partial(_serial_ws_kernel, n=n, k=k),
+        in_specs=[smem] * 4,
+        out_specs=[smem] * 3,
+        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 3,
+        interpret=_resolve(interpret),
+    )(op, li, val, ws)
 
 
 # ----------------------------------------------------------- gather kernel --

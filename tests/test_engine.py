@@ -4,10 +4,13 @@ equivalent result; GIDs reflect serial order; state is recoverable.
 Deterministic seed sweeps live here; the hypothesis-driven property
 versions are in test_engine_properties.py (skipped when hypothesis is not
 installed)."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.engine import SwitchEngine
+from repro.core import engine
+from repro.core.engine import _ENGINE_IMPLS, ShardedSwitchEngine, SwitchEngine
 from repro.core.packets import (ADD, ADDP, CADD, NOP, READ, WRITE,
                                 SwitchConfig, empty_packets, make_packet,
                                 mark_multipass, split_passes)
@@ -141,3 +144,204 @@ def test_snapshot_restore():
     e.restore(snap)
     np.testing.assert_array_equal(e.read_all(), snap[0])
     assert e.next_gid == snap[1]
+
+
+# ------------------------------------------- plain-Python serial oracle --
+
+def serial_loop_ref(regs0, p):
+    """The switch's serial semantics as a plain loop over rows, then
+    instructions: NOP does nothing; READ returns the register; WRITE
+    stores the operand; ADD adds it; CADD adds it only if the sum is >= 0
+    (else the register is kept, ``ok`` is false and the result is the
+    unchanged value); ADDP adds the result of instruction ``operand`` of
+    the same txn.  Returns (results, ok, registers)."""
+    regs = [[int(x) for x in row] for row in regs0]
+    B, K = p["op"].shape
+    res = [[0] * K for _ in range(B)]
+    ok = [[True] * K for _ in range(B)]
+    for b in range(B):
+        for k in range(K):
+            o = int(p["op"][b, k])
+            s, r = int(p["stage"][b, k]), int(p["reg"][b, k])
+            v = int(p["operand"][b, k])
+            if o == NOP:
+                continue
+            cur = regs[s][r]
+            if o == READ:
+                res[b][k] = cur
+                continue
+            if o == WRITE:
+                new = v
+            elif o == ADD:
+                new = cur + v
+            elif o == ADDP:
+                new = cur + res[b][v]
+            else:                                  # CADD
+                new = cur + v
+                if new < 0:
+                    ok[b][k], new = False, cur
+            regs[s][r] = res[b][k] = new
+    return np.array(res), np.array(ok), np.array(regs)
+
+
+def _rows(rows, K=CFG.max_instrs):
+    """Packets from explicit rows of (op, stage, reg, operand) tuples,
+    NOP-padded to K like the packet builder pads them."""
+    p = empty_packets(len(rows), CFG)
+    for b, row in enumerate(rows):
+        for k, (o, s, r, v) in enumerate(row):
+            p["op"][b, k], p["stage"][b, k] = o, s
+            p["reg"][b, k], p["operand"][b, k] = r, v
+    return p
+
+
+def _mixed_batch(rng, B, K=CFG.max_instrs):
+    """Every opcode, slots drawn from a few registers so they repeat
+    within and across txns, ADDP sources at any earlier instruction
+    (whatever its stage), NOP padding at each row's tail."""
+    p = empty_packets(B, CFG)
+    for b in range(B):
+        n = int(rng.integers(0, K + 1))
+        for k in range(n):
+            o = int(rng.choice([READ, WRITE, ADD, CADD, ADDP] if k else
+                               [READ, WRITE, ADD, CADD]))
+            p["op"][b, k] = o
+            p["stage"][b, k] = rng.integers(0, CFG.n_stages)
+            p["reg"][b, k] = rng.integers(0, 3)
+            p["operand"][b, k] = (rng.integers(0, k) if o == ADDP else
+                                  rng.integers(-60, 40))
+    return p
+
+
+SERIAL_CASES = {
+    # balance 5: -9 fails (kept, ok false), -3 applies, then +1
+    "cadd_fails_and_applies": lambda rng: _rows(
+        [[(CADD, 2, 3, -9), (CADD, 2, 3, -3), (CADD, 2, 3, 1)],
+         [(CADD, 2, 3, -3), (READ, 2, 3, 0)]]),
+    # multipass ADDP: the source READ sits at the same stage, or later
+    "addp_source_same_stage": lambda rng: _rows(
+        [[(READ, 3, 2, 0), (ADDP, 3, 5, 0), (ADDP, 3, 2, 1)]]),
+    "addp_source_later_stage": lambda rng: _rows(
+        [[(READ, 4, 1, 0), (ADDP, 1, 5, 0), (READ, 5, 0, 0),
+          (ADDP, 0, 7, 2)],
+         [(ADD, 4, 1, 3), (READ, 1, 5, 0)]]),
+    "slot_repeated_in_and_across_txns": lambda rng: _rows(
+        [[(ADD, 1, 4, 2), (READ, 1, 4, 0), (WRITE, 1, 4, 9),
+          (ADD, 1, 4, -1)],
+         [(READ, 1, 4, 0), (CADD, 1, 4, -20), (ADD, 1, 4, 5)],
+         [(READ, 1, 4, 0)]]),
+    "nop_padding": lambda rng: _rows(
+        [[(NOP, 0, 0, 0), (ADD, 2, 2, 4), (NOP, 0, 0, 0),
+          (READ, 2, 2, 0)], [], [(WRITE, 5, 15, 3)]]),
+    # a real op on slot (0, 0) while every padding NOP also points there
+    "slot_00_shared_with_padding": lambda rng: _rows(
+        [[(ADD, 0, 0, 7), (READ, 0, 0, 0)], [], [(WRITE, 0, 0, -2)],
+         [(CADD, 0, 0, 1), (READ, 3, 3, 0)], []]),
+    **{f"random_b{B}": (lambda rng, B=B: _mixed_batch(rng, B))
+       for B in (1, 3, 8, 64, 200)},          # 200 pads to 256
+}
+
+
+@pytest.mark.parametrize("case", sorted(SERIAL_CASES))
+def test_serial_equals_loop_reference(case):
+    rng = np.random.default_rng(sorted(SERIAL_CASES).index(case))
+    p = SERIAL_CASES[case](rng)
+    regs0 = rng.integers(0, 30, (CFG.n_stages, CFG.regs_per_stage))
+    regs0[2, 3] = 5
+    want_res, want_ok, want_regs = serial_loop_ref(regs0, p)
+    e = SwitchEngine(CFG, regs0)
+    res, ok, _ = e.execute(p, mode="serial")
+    np.testing.assert_array_equal(res, want_res)
+    np.testing.assert_array_equal(ok, want_ok)
+    np.testing.assert_array_equal(e.read_all(), want_regs)
+
+
+def test_serial_row_blocks_equal_loop_reference(monkeypatch):
+    """A batch above one kernel call's SMEM budget runs as consecutive
+    row blocks, each seeing the writes of the blocks before it."""
+    monkeypatch.setattr(engine, "_SERIAL_CALL_INSTRS", 4 * CFG.max_instrs)
+    rng = np.random.default_rng(11)
+    p = _mixed_batch(rng, 30)
+    regs0 = rng.integers(0, 30, (CFG.n_stages, CFG.regs_per_stage))
+    want_res, want_ok, want_regs = serial_loop_ref(regs0, p)
+    # a fresh function object, so the patched budget is traced
+    regs, res, ok = jax.jit(lambda *a: _ENGINE_IMPLS["serial"](*a))(
+        regs0.astype(np.int32), p["op"], p["stage"], p["reg"], p["operand"])
+    np.testing.assert_array_equal(res, want_res)
+    np.testing.assert_array_equal(ok, want_ok)
+    np.testing.assert_array_equal(regs, want_regs)
+
+
+def _loop_carries(jaxpr):
+    """Avals of every scan / while carry in ``jaxpr`` and its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        prm = eqn.params
+        if eqn.primitive.name == "scan":
+            nc = prm["num_consts"]
+            yield from (v.aval for v in
+                        eqn.invars[nc:nc + prm["num_carry"]])
+        elif eqn.primitive.name == "while":
+            n = prm["cond_nconsts"] + prm["body_nconsts"]
+            yield from (v.aval for v in eqn.invars[n:])
+        for val in prm.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _loop_carries(sub)
+
+
+@pytest.mark.parametrize("Bp", [8, 1024])
+def test_serial_loop_carries_no_register_plane(Bp):
+    """At the benchmark's 20 x 65,536 plane no loop of the serial engine
+    carries the register plane: its loop runs on the batch's working
+    set, at most ``Bp * K`` words."""
+    S, R, K = 20, 65536, 8
+    spec = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(_ENGINE_IMPLS["serial"])(
+        spec((S, R), jnp.int32), *[spec((Bp, K), jnp.int32)] * 4)
+    sizes = [int(np.prod(a.shape)) for a in _loop_carries(closed.jaxpr)]
+    assert sizes, "the serial engine has no loop"
+    assert S * R not in sizes and max(sizes) <= Bp * K
+
+
+# ------------------------------------------------- serial counters ----
+
+def test_serial_counters_count_steps_and_instrs():
+    p = _rows([[(CADD, 0, 1, -1), (READ, 2, 2, 0)],
+               [(ADD, 1, 1, 3), (NOP, 0, 0, 0), (WRITE, 4, 0, 2)],
+               [(READ, 5, 5, 0)]])
+    e = SwitchEngine(CFG)
+    e.execute(p)                                   # auto -> serial (CADD)
+    assert (e.serial_steps, e.serial_instrs) == (4 * 5, 5)
+    e.execute(p, mode="serial")
+    assert (e.serial_steps, e.serial_instrs) == (2 * 20, 2 * 5)
+
+
+@pytest.mark.parametrize("mode", ["affine", "staged"])
+def test_serial_counters_ignore_other_engines(mode):
+    rng = np.random.default_rng(5)
+    p = (random_batch(rng, 8, CFG.max_instrs) if mode == "affine" else
+         staged_addp_batch(rng, 8))
+    e = SwitchEngine(CFG)
+    e.execute(p, mode=mode)
+    assert e.dispatch_count == 1
+    assert (e.serial_steps, e.serial_instrs) == (0, 0)
+
+
+def test_sharded_serial_counters_sum_over_planes():
+    cfg = SwitchConfig(n_stages=CFG.n_stages,
+                       regs_per_stage=CFG.regs_per_stage,
+                       max_instrs=CFG.max_instrs, n_switches=2)
+    S = cfg.n_stages
+    # one row per shard (a B = 1 group of K steps on each plane), and one
+    # cross-shard row: two 1 x 1 serial mini-dispatches
+    p = _rows([[(CADD, 1, 2, -1), (READ, 2, 2, 0)],
+               [(ADD, S + 1, 1, 3), (READ, S + 3, 0, 0)],
+               [(READ, 0, 0, 0), (ADD, S, 0, 4)]])
+    e = ShardedSwitchEngine(cfg)
+    e.execute(p)
+    K = CFG.max_instrs
+    assert e.serial_steps == sum(q.serial_steps for q in e.planes)
+    assert e.serial_instrs == sum(q.serial_instrs for q in e.planes)
+    assert e.serial_steps == K + K + 2
+    assert e.serial_instrs == 2 + 2 + 2

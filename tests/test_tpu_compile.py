@@ -8,7 +8,8 @@ registers, a 5 MiB register file), batch B = 256, K = 8 instructions:
     (Mosaic refuses what interpret mode accepts: scalar VMEM stores, more
     VMEM or SMEM than a core has);
   * the three fused jit engines (``serial`` / ``affine`` / ``staged``) the
-    hot path dispatches, donation included;
+    hot path dispatches, donation included, the serial engine's Pallas
+    loop compiled for Mosaic;
   * the READ-only gather of the read tier.
 
 Nothing runs, so this proves compilation only, not results (the kernels'
@@ -27,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.engine import _bucket, _compiled_engine, _compiled_reader
 from repro.core.packets import SwitchConfig
 from repro.kernels.switch_txn import ops as ktx
+from repro.kernels.switch_txn import switch_txn
 
 CFG = SwitchConfig()                       # 20 x 65,536: the paper's budget
 S, R = CFG.n_stages, CFG.regs_per_stage
@@ -90,11 +92,14 @@ def test_switch_txn_kernel_compiles(chip, name):
 
 
 @pytest.mark.parametrize("mode", ["serial", "affine", "staged"])
-def test_fused_engine_compiles(chip, mode):
+def test_fused_engine_compiles(chip, mode, monkeypatch):
+    # as on the chip, the serial engine's Pallas loop compiles for Mosaic
+    monkeypatch.setattr(switch_txn, "interpret_default", lambda: False)
     fn = _compiled_engine(mode, S, R, B, K, _bucket(M), chip)
     mem = fn.memory_analysis()
     # the register file is donated: updated in place, not copied
     assert mem.alias_size_in_bytes >= S * R * 4
+    assert ("tpu_custom_call" in fn.as_text()) == (mode == "serial")
 
 
 def test_read_gather_compiles(chip):

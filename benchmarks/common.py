@@ -49,15 +49,19 @@ def smallbank_profiles(hot_per_node=10, dist=0.2, n=3000, layout="optimal",
     return [profile_txn(t, hi, t.home) for t in txns], hi
 
 
-def tpcc_profiles(warehouses=8, dist=0.2, n=3000, layout="optimal", seed=0):
-    p = tpcc.TPCCParams(n_nodes=N_NODES, n_warehouses=warehouses,
-                        dist_frac=dist)
+def tpcc_profiles(warehouses=8, dist=None, n=3000, layout="optimal",
+                  seed=0):
+    """``dist``: the share of remote order lines and of remote payments
+    alike; None keeps the spec's 1% and 15%."""
+    p = tpcc.TPCCParams(n_nodes=N_NODES, n_warehouses=warehouses)
+    if dist is not None:
+        p.remote_line_frac = p.remote_payment_frac = dist
     rng = np.random.default_rng(seed)
-    sample = tpcc.generate(rng, 5000, p)
+    sample = tpcc.generate(rng, 20000, p)
     lf = random_layout if layout == "random" else None
     kw = dict(layout_fn=lf) if lf else {}
-    nhot = warehouses * (1 + 2 * tpcc.N_DISTRICTS + tpcc.HOT_ITEMS)
-    hi = build_hot_index(tpcc.traces(sample), top_k=nhot, switch=SWITCH, **kw)
+    hi = build_hot_index(tpcc.traces(sample), top_k=len(tpcc.hot_keys(p)),
+                         switch=SWITCH, **kw)
     txns = tpcc.generate(np.random.default_rng(seed + 1), n, p)
     return [profile_txn(t, hi, t.home) for t in txns], hi
 

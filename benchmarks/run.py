@@ -142,7 +142,7 @@ def fig14_tpcc(fast=True):
         r = {k: C.run_sim(profs, SystemConfig(kind=k))
              for k in ("p4db", "noswitch")}
         sp = r["p4db"]["throughput"] / max(r["noswitch"]["throughput"], 1)
-        rows.append([wh, 0.2, r["p4db"]["throughput"],
+        rows.append([wh, "spec", r["p4db"]["throughput"],
                      r["noswitch"]["throughput"], sp])
         emit(f"fig14_tpcc_wh{wh}", r["p4db"].get("lat_all", 0) * 1e6,
              f"speedup={sp:.2f}x")

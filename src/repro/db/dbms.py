@@ -564,6 +564,8 @@ class Cluster:
             if kind == "warm":
                 # a warm txn touches hot keys: dispatch the buffered group
                 # AND sync every outstanding handle (consistency point)
+                if pending:
+                    self.stats["warm_syncs"] += 1
                 self._flush_hot_group(pending, results, tr=tr)
                 self.drain()
             results[i] = self._run_traced(txn, kind, max_retries, tr)
@@ -876,9 +878,11 @@ class Cluster:
         return results
 
     # warm: cold part made abort-proof first, then the switch sub-txn
-    # (paper §6.2, Fig 8/10)
+    # (paper §6.2, Fig 8/10).  The split into sub-txns and the merge of
+    # their results are ``warm-split`` spans
     def _run_warm(self, txn: Txn, tr=None):
         self._ts += 1
+        t0 = tr.clock() if tr is not None else 0.0
         hot_keys = {k for k in txn.keys() if self.hot_index.is_hot(k)}
         cold_ops = [(i, (o, k, v)) for i, (o, k, v) in enumerate(txn.ops)
                     if k not in hot_keys]
@@ -890,6 +894,8 @@ class Cluster:
                        tid=txn.tid)
         hot_txn = Txn(txn.kind, [op for _, op in hot_ops], txn.home,
                       tid=txn.tid)
+        if tr is not None:
+            tr.add_span("warm-split", t0, tr.clock())
         # an explicit switch_mode that rejects the hot sub-txn must fail
         # BEFORE the cold part takes locks and applies/logs its writes
         if self.switch_mode != "auto":
@@ -904,18 +910,22 @@ class Cluster:
                 tr.add_span("cold", t0, tr.clock())
         # cold part can no longer abort -> send switch sub-txn
         hot_res = self._run_hot(hot_txn, tr=tr)
+        self.stats["warm_dispatches"] += 1
         # commit cold part everywhere (2PC decision broadcast)
         t0 = tr.clock() if tr is not None else 0.0
         for p in {node_of(k) for k in cold_txn.keys()}:
             self.nodes[p].log("commit", txn.tid)
             self.nodes[p].release_all(txn.tid)
         if tr is not None:
-            tr.add_span("cold", t0, tr.clock())
+            t1 = tr.clock()
+            tr.add_span("cold", t0, t1)
         results = [0] * len(txn.ops)
         for (i, _), r in zip(cold_ops, cold_res):
             results[i] = r
         for (i, _), r in zip(hot_ops, hot_res):
             results[i] = r
+        if tr is not None:
+            tr.add_span("warm-split", t1, tr.clock())
         return results
 
     # ----------------------------------------------- faults & durability --

@@ -26,6 +26,8 @@ STAT_NAMES = {
     "hot":            ("txns_hot_total", "hot-classified admissions (once per txn)"),
     "cold":           ("txn_attempts_cold_total", "cold execution attempts incl. retries"),
     "warm":           ("txn_attempts_warm_total", "warm execution attempts incl. retries"),
+    "warm_dispatches": ("warm_switch_dispatches_total", "switch sub-txns of warm txns"),
+    "warm_syncs":     ("warm_consistency_syncs_total", "run_batch consistency points at which a warm txn flushed a pending hot group"),
     "commits":        ("txns_committed_total", "committed transactions"),
     "aborts":         ("txn_aborts_total", "2PL aborts (before any retry)"),
     "gave_up":        ("txns_gave_up_total", "txns dropped after exhausting retries"),
@@ -56,8 +58,8 @@ SIM_ALIASES = {
 }
 
 # Span vocabularies (trace point names, in causal order).
-FUNCTIONAL_SPANS = ("classify", "cold", "packet-build", "wal-send",
-                    "dispatch", "drain", "scatter")
+FUNCTIONAL_SPANS = ("classify", "warm-split", "cold", "packet-build",
+                    "wal-send", "dispatch", "drain", "scatter")
 SIM_SPANS = ("admission", "batcher-join", "switch-service", "commit")
 
 # Shared histogram / gauge names used by both instrumented layers.

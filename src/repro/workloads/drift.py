@@ -187,8 +187,6 @@ class TPCCWarehouseRotation:
     def __post_init__(self):
         self._p = tpcc.TPCCParams(n_nodes=self.n_nodes,
                                   n_warehouses=self.n_warehouses,
-                                  dist_frac=self.dist_frac,
-                                  items_per_order=self.items_per_order,
                                   n_items=self.n_items,
                                   n_customers=self.n_customers)
 

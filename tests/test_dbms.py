@@ -43,12 +43,20 @@ def test_ycsb_state_equivalence():
 
 
 def test_tpcc_warm_transactions():
+    """Every NewOrder and Payment is warm: one switch sub-txn per commit,
+    and the same final state as the cluster without a switch."""
     p = tpcc.TPCCParams(n_nodes=4, n_warehouses=8)
-    sample = tpcc.generate(np.random.default_rng(0), 800, p)
-    hi = build_hot_index(tpcc.traces(sample), 250, SW)
+    sample = tpcc.generate(np.random.default_rng(0), 10000, p)
+    hot = tpcc.hot_keys(p)
+    hi = build_hot_index(tpcc.traces(sample), len(hot), SW)
+    assert set(hi.placement.slot) == set(hot)
     txns = tpcc.generate(np.random.default_rng(1), 200, p)
-    c1, _ = _run_pair(txns, hi)
-    assert c1.stats["warm"] > 0
+    assert {hi.classify(tr) for tr in tpcc.traces(txns)} == {"warm"}
+    c1, c2 = _run_pair(txns, hi)
+    assert c1.stats["warm"] == c1.stats["warm_dispatches"] == len(txns)
+    assert c1.stats["commits"] == c2.stats["commits"] == len(txns)
+    assert c1.stats["hot"] == c1.stats["cold"] == 0
+    assert c2.stats["warm_dispatches"] == 0
 
 
 def test_switch_recovery_from_wals():

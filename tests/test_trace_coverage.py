@@ -1,9 +1,11 @@
 """What the served path's spans and counters cover, and that they change
 nothing.
 
-  * ``run_batch`` + ``drain`` over a mixed hot/warm/cold batch record the
-    ``cold``, ``wal-send``, ``scatter`` and ``packet-build`` spans, and no
-    two spans overlap: each instant of a call is charged to one span.
+  * ``run_batch`` + ``drain`` over a mixed hot/warm/cold batch, and over
+    TPC-C NewOrder + Payment (every txn warm), record the ``cold``,
+    ``warm-split``, ``wal-send``, ``scatter`` and ``packet-build`` spans,
+    and no two spans overlap: each instant of a call is charged to one
+    span.
   * Every ``build_packets`` call of the batch path, the whole-group build
     of ``_flush_hot_group`` included, runs inside a ``packet-build`` span.
   * The WAL counters equal the records in the logs and the bytes their
@@ -31,7 +33,7 @@ from repro.db.dbms import Cluster
 from repro.db.txn import Txn, node_of
 from repro.db.wal import _canon
 from repro.obs import FUNCTIONAL_SPANS, GcMeter, Tracer
-from repro.workloads import smallbank, ycsb
+from repro.workloads import smallbank, tpcc, ycsb
 
 SW = SwitchConfig(n_stages=16, regs_per_stage=512, max_instrs=16)
 
@@ -68,7 +70,20 @@ def _smallbank():
         tuple((k, 100) for k in smallbank.hot_keys(p))
 
 
-WORKLOADS = dict(ycsb=_ycsb, smallbank=_smallbank)
+@functools.lru_cache(maxsize=None)
+def _tpcc():
+    p = tpcc.TPCCParams(n_nodes=2, n_warehouses=2)
+    hot = tpcc.hot_keys(p)
+    hi = build_hot_index([[(k, ADD) for k in hot]], len(hot), SW)
+    txns = tpcc.generate(np.random.default_rng(1), 128, p)
+    return 2, hi, txns, tuple(tpcc.initial_values(
+        np.random.default_rng(2), hot).items())
+
+
+WORKLOADS = dict(ycsb=_ycsb, smallbank=_smallbank, tpcc=_tpcc)
+# the classes each workload's batch holds
+KINDS = dict(ycsb={"hot", "warm", "cold"}, smallbank={"hot", "warm", "cold"},
+             tpcc={"warm"})
 
 
 def _cluster(workload, async_hot, **kw):
@@ -107,11 +122,11 @@ def _spans(c):
 def test_batch_path_spans_are_disjoint_and_named(workload, async_hot):
     c, txns, _ = _serve(workload, async_hot, tracer=Tracer(capacity=1 << 16))
     kinds = {c.classify(t) for t in txns}
-    assert kinds == {"hot", "warm", "cold"}          # a mixed batch
+    assert kinds == KINDS[workload]
     spans = sorted(_spans(c), key=lambda s: (s.t0, s.t1))
     names = {s.name for s in spans}
-    assert {"classify", "cold", "packet-build", "wal-send", "dispatch",
-            "drain", "scatter"} <= names
+    assert {"classify", "warm-split", "cold", "packet-build", "wal-send",
+            "dispatch", "drain", "scatter"} <= names
     assert names <= set(FUNCTIONAL_SPANS)
     assert all(s.depth == 0 and s.t1 >= s.t0 for s in spans)
     for a, b in zip(spans, spans[1:]):

@@ -1054,11 +1054,13 @@ class Cluster:
 
     def wal_counters(self) -> dict:
         """WAL appends summed over the nodes: records, bytes of their
-        canonical encoding, and seconds in ``append`` (timed only when
-        the cluster was handed a tracer)."""
+        canonical encoding, records whose body a shape template built, and
+        seconds in ``append`` (timed only when the cluster was handed a
+        tracer)."""
         wals = [n.wal for n in self.nodes if isinstance(n.wal, SegmentedWAL)]
         return dict(records=sum(w.records_appended for w in wals),
                     bytes=sum(w.bytes_appended for w in wals),
+                    templated=sum(w.records_templated for w in wals),
                     append_s=sum(w.append_s for w in wals))
 
     # --------------------------------------------------------- telemetry --

@@ -11,7 +11,13 @@ claim.  This module provides the real thing behind the same ``log()`` API:
     Every record carries a SHA-256 hash over (previous record's hash,
     canonical JSON of the record body), so the log is a hash chain:
     corruption of any byte, reordering, or deletion of an interior record
-    breaks the chain and is caught by ``verify()``.  A segment that fills
+    breaks the chain and is caught by ``verify()``.  ``append`` builds the
+    body as ``_canon_record`` does: a shape template for the four kinds the
+    served path logs (``write``, ``commit``, ``switch_send``,
+    ``switch_result``) when every value is a plain ``int``, else one C
+    encoder built at import; both are byte-identical to ``json.dumps``
+    (``_canon``).  ``verify()`` re-derives every hash with ``_canon``, so
+    each integrity walk also checks the fast encoding.  A segment that fills
     is *sealed* — its record count and final hash are frozen in the
     segment metadata — so truncation of anything but the open tail
     segment is also detectable.  The open tail is the one place a crash
@@ -74,6 +80,94 @@ def _canon(obj) -> bytes:
                       default=_jsonable).encode()
 
 
+# ---- fast canonical bodies: byte-identical to _canon([lsn, kind, tid, p])
+# A template applies only when every value is exactly ``int``: ``%d`` and
+# ``str`` print a bool or a numpy integer unlike JSON does.
+
+def _write_body(lsn, tid, p):
+    if len(p) == 3:
+        key, new, old = p.get("key"), p.get("new"), p.get("old")
+        if type(key) is type(new) is type(old) is int:
+            return (b'[%d,"write",%d,{"key":%d,"new":%d,"old":%d}]'
+                    % (lsn, tid, key, new, old))
+    return None
+
+
+def _commit_body(lsn, tid, p):
+    return b'[%d,"commit",%d,{}]' % (lsn, tid) if not p else None
+
+
+def _send_body(lsn, tid, p):
+    ops = p.get("ops")
+    if len(p) != 1 or type(ops) is not list:
+        return None
+    parts = []
+    for op in ops:
+        if (type(op) is not tuple and type(op) is not list) or len(op) != 3:
+            return None
+        a, b, c = op
+        if not type(a) is type(b) is type(c) is int:
+            return None
+        parts.append(b"[%d,%d,%d]" % (a, b, c))
+    return (b'[%d,"switch_send",%d,{"ops":[%s]}]'
+            % (lsn, tid, b",".join(parts)))
+
+
+def _result_body(lsn, tid, p):
+    gid, res = p.get("gid"), p.get("results")
+    if (len(p) != 2 or type(gid) is not int or type(res) is not list
+            or not set(map(type, res)) <= {int}):
+        return None
+    return (b'[%d,"switch_result",%d,{"gid":%d,"results":[%s]}]'
+            % (lsn, tid, gid, ",".join(map(str, res)).encode()))
+
+
+_TEMPLATES = {"write": _write_body, "commit": _commit_body,
+              "switch_send": _send_body, "switch_result": _result_body}
+
+
+def _template_body(lsn, kind, tid, payload) -> Optional[bytes]:
+    """The record's body from its kind's shape template, or None when the
+    kind, the payload's keys or a value's type falls outside it."""
+    fn = _TEMPLATES.get(kind) if type(kind) is str else None
+    if (fn is None or type(payload) is not dict or type(lsn) is not int
+            or type(tid) is not int):
+        return None
+    return fn(lsn, tid, payload)
+
+
+def _make_encoder():
+    """``json.dumps``'s C encoder with ``_canon``'s settings, built once
+    instead of per call; None without the C accelerator.  It keeps no
+    circular-reference markers, so it holds no state between calls: a
+    circular payload ends in ``RecursionError`` and ``_canon`` then raises
+    what ``json.dumps`` raises."""
+    try:
+        return json.encoder.c_make_encoder(
+            None, _jsonable, json.encoder.encode_basestring_ascii, None,
+            ":", ",", True, False, True)
+    except (AttributeError, TypeError):
+        return None
+
+
+_ENCODER = _make_encoder()
+
+
+def _encode(obj) -> bytes:
+    if _ENCODER is not None:
+        try:
+            return "".join(_ENCODER(obj, 0)).encode()
+        except Exception:
+            pass        # whatever it raised, _canon raises as json.dumps does
+    return _canon(obj)
+
+
+def _canon_record(lsn: int, kind: str, tid: int, payload) -> bytes:
+    """``_canon([lsn, kind, tid, payload])``, byte for byte, built faster."""
+    body = _template_body(lsn, kind, tid, payload)
+    return _encode([lsn, kind, tid, payload]) if body is None else body
+
+
 def _chain_hash(prev: str, body: bytes) -> str:
     return hashlib.sha256(prev.encode() + body).hexdigest()
 
@@ -106,9 +200,10 @@ class SegmentMeta:
 class SegmentedWAL:
     """Segmented append-only hash-chained log (see module docstring).
 
-    Counters: ``records_appended`` and ``bytes_appended`` (the canonical
-    encoding each record's hash covers) on every append; ``append_s``, the
-    time spent in ``append``, only once an owner sets ``clock``."""
+    Counters: ``records_appended``, ``bytes_appended`` (the canonical
+    encoding each record's hash covers) and ``records_templated`` (bodies a
+    shape template built) on every append; ``append_s``, the time spent in
+    ``append``, only once an owner sets ``clock``."""
 
     def __init__(self, segment_size: int = DEFAULT_SEGMENT_SIZE):
         if segment_size < 1:
@@ -119,6 +214,7 @@ class SegmentedWAL:
         self.clock = None
         self.records_appended = 0
         self.bytes_appended = 0
+        self.records_templated = 0
         self.append_s = 0.0
 
     # ------------------------------------------------------------ append
@@ -135,7 +231,11 @@ class SegmentedWAL:
             seg = SegmentMeta(seg.index + 1, lsn)
             self._segments.append(seg)
         tid = int(tid)
-        body = _canon([lsn, kind, tid, payload])
+        body = _template_body(lsn, kind, tid, payload)
+        if body is None:
+            body = _encode([lsn, kind, tid, payload])
+        else:
+            self.records_templated += 1
         rec = WALRecord(lsn, kind, tid, payload, prev, _chain_hash(prev, body))
         self._records.append(rec)
         seg.count += 1

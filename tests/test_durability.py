@@ -11,7 +11,11 @@ Pin inventory:
     truncation, and accepts a torn open tail;
   * warm-standby takeover replays ONLY post-checkpoint sends;
   * same log => byte-identical replay (hypothesis-shim property test);
-  * default-off sim knobs leave the result dict untouched.
+  * default-off sim knobs leave the result dict untouched;
+  * ``_canon_record`` (shape templates, the prebuilt encoder) is byte for
+    byte ``_canon``, raises what ``json.dumps`` raises, and on served
+    YCSB-A / SmallBank / TPC-C NP traffic builds >= 99% of the bodies from
+    templates while every hash equals the reference encoder's.
 """
 import copy
 
@@ -24,8 +28,10 @@ from repro.core.packets import ADD, READ, SwitchConfig
 from repro.db.dbms import Cluster, LogEntry
 from repro.db.faults import FaultPlan, SimulatedCrash, SwitchUnavailable
 from repro.db.wal import (CheckpointStore, SegmentedWAL, WALIntegrityError,
-                          main as wal_cli)
+                          _canon, _canon_record, main as wal_cli,
+                          record_hash)
 from repro.db.txn import Txn, key_of
+from repro.workloads import smallbank, tpcc, ycsb
 
 SW = SwitchConfig(n_stages=8, regs_per_stage=128, max_instrs=8)
 KEYS = [key_of(n, i) for n in range(2) for i in range(12)]
@@ -136,6 +142,152 @@ def test_checkpoint_store_reconstructs_from_diffs():
         assert entry["kind"] == "incremental" and entry["n_changed"] <= 1
     np.testing.assert_array_equal(cs.reconstruct(), cs.state())
     np.testing.assert_array_equal(cs.reconstruct(), regs)
+
+
+# ===================================================================== #
+#  Canonical record bodies                                              #
+# ===================================================================== #
+
+def _circular():
+    loop = []
+    loop.append(loop)
+    return dict(ops=loop)
+
+
+_OPS = [(ADD, KEYS[0], 3), (READ, KEYS[13], 0)]
+# (kind, payload): every kind the DBMS logs, then payloads the shape
+# templates must pass to the general encoder, then ones json.dumps rejects
+CANON_CASES = dict(
+    write=("write", dict(key=KEYS[0], old=5, new=8)),
+    commit=("commit", {}),
+    switch_send=("switch_send", dict(ops=list(_OPS))),
+    switch_result=("switch_result", dict(gid=12, results=[8, 0])),
+    early_abort=("early_abort", dict(attempt=2)),
+    ckpt=("ckpt", dict(reason="migration", n_changed=7)),
+    np_int64_write=("write", dict(key=np.int64(KEYS[0]), old=5, new=8)),
+    np_int32_op=("switch_send", dict(ops=[(np.int32(ADD), KEYS[0], 3)])),
+    np_int64_results=("switch_result",
+                      dict(gid=1, results=[np.int64(4), 5])),
+    np_array_results=("switch_result",
+                      dict(gid=np.int32(1), results=np.arange(3))),
+    np_array_ops=("switch_send", dict(ops=np.array(_OPS))),
+    bool_values=("write", dict(key=True, old=False, new=1)),
+    bool_result=("switch_result", dict(gid=1, results=[True, 0])),
+    big_and_negative_ints=("write", dict(key=-(2 ** 70), old=2 ** 63,
+                                         new=-1)),
+    floats=("switch_result", dict(gid=1, results=[1.5, float("nan"),
+                                                  float("inf"),
+                                                  float("-inf"), -0.0])),
+    np_float=("write", dict(key=1, old=np.float32(0.1), new=2.0)),
+    sets=("ckpt", dict(reason="x", keys={3, 1, 2},
+                       frozen=frozenset({-1}))),
+    nested_lists=("switch_send", dict(ops=[[ADD, [1, [2]], 3]])),
+    non_ascii_and_escapes=("ckpt", {"reason": 'h\u00e9llo "q"\n\t\\ \u2603',
+                                    "cl\u00e9\n": 1, "a": None}),
+    empty_list=("switch_send", dict(ops=[])),
+    empty_results=("switch_result", dict(gid=0, results=[])),
+    write_extra_key=("write", dict(key=1, old=2, new=3, x=4)),
+    write_missing_key=("write", dict(key=1, old=2)),
+    commit_payload=("commit", dict(by="gc")),
+    tuple_ops=("switch_send", dict(ops=tuple(_OPS))),
+    short_op=("switch_send", dict(ops=[(ADD, 1)])),
+    tuple_results=("switch_result", dict(gid=1, results=(1,))),
+    unknown_kind=("migrate_begin", dict(epoch=3, moved=[1, 2])),
+    unserializable=("ckpt", dict(reason=object())),
+    circular=("switch_send", _circular()),
+)
+
+
+def _body_or_error(fn):
+    try:
+        return fn()
+    except Exception as e:       # the class is what must agree
+        return type(e)
+
+
+@pytest.mark.parametrize("case", sorted(CANON_CASES))
+def test_canon_record_is_byte_identical_to_canon(case):
+    kind, payload = CANON_CASES[case]
+    for lsn, tid in ((0, 0), (123456, 7654321), (5, -3)):
+        fast = _body_or_error(lambda: _canon_record(lsn, kind, tid, payload))
+        ref = _body_or_error(lambda: _canon([lsn, kind, tid, payload]))
+        assert fast == ref
+        assert isinstance(ref, bytes) == (case not in ("unserializable",
+                                                       "circular"))
+
+
+def _served_wal_run(workload, async_hot, seed=11):
+    """Seeded YCSB-A / SmallBank / TPC-C NP traffic through ``run_batch``
+    in drained batches of 64, as the benchmark serves it."""
+    rng = np.random.default_rng(seed)
+    sw = SwitchConfig(n_stages=16, regs_per_stage=512, max_instrs=16)
+    loads = ()
+    if workload == "ycsb":
+        p = ycsb.YCSBParams(n_nodes=4, keys_per_node=1000, hot_per_node=16)
+        hi = build_hot_index(ycsb.traces(ycsb.generate(rng, 1500, p)), 64,
+                             sw)
+        txns = ycsb.generate(rng, 256, p)
+    elif workload == "smallbank":
+        p = smallbank.SmallBankParams(n_nodes=2, accounts_per_node=50,
+                                      hot_per_node=4)
+        hi = build_hot_index(
+            smallbank.traces(smallbank.generate(rng, 2000, p)), 16, sw)
+        txns = smallbank.generate(rng, 256, p)
+        loads = [(k, 100) for k in smallbank.hot_keys(p)]
+    else:
+        p = tpcc.TPCCParams(n_nodes=2, n_warehouses=2)
+        hot = tpcc.hot_keys(p)
+        hi = build_hot_index([[(k, ADD) for k in hot]], len(hot), sw)
+        txns = tpcc.generate(rng, 128, p)
+        loads = tpcc.initial_values(rng, hot).items()
+    c = Cluster(p.n_nodes, sw, hi, async_hot=async_hot)
+    for k, v in loads:
+        c.load(k, v)
+    c.snapshot_offload()
+    for i in range(0, len(txns), 64):
+        c.run_batch(txns[i:i + 64])
+        c.drain()
+    return c
+
+
+@pytest.mark.parametrize("async_hot", [False, True])
+@pytest.mark.parametrize("workload", ["ycsb", "smallbank", "tpcc"])
+def test_fast_bodies_keep_the_hash_chain(workload, async_hot, tmp_path):
+    c = _served_wal_run(workload, async_hot)
+    w = c.wal_counters()
+    assert w["records"] == sum(len(n.wal) for n in c.nodes) > 0
+    assert w["templated"] >= 0.99 * w["records"]
+    assert w["bytes"] == sum(len(_canon([r.lsn, r.kind, r.tid, r.payload]))
+                             for n in c.nodes for r in n.wal)
+    for n in c.nodes:
+        prev = "0" * 64
+        for r in n.wal:
+            assert r.prev == prev
+            assert r.hash == record_hash(prev, r.lsn, r.kind, r.tid,
+                                         r.payload)
+            prev = r.hash
+        report = n.wal.verify()
+        d = str(tmp_path / f"node{n.id}")
+        n.wal.save(d)
+        loaded = SegmentedWAL.load(d)
+        assert loaded.verify() == report
+        assert [r.hash for r in loaded] == [r.hash for r in n.wal]
+        assert wal_cli(["verify", d]) == 0
+
+
+def test_numpy_payloads_take_no_template():
+    wal = SegmentedWAL(segment_size=4)
+    k = np.int64(KEYS[0])
+    for i in range(3):
+        wal.append("write", i, dict(key=k, old=np.int64(5), new=np.int64(i)))
+        wal.append("switch_send", i, dict(ops=[(np.int64(ADD), k, 1)]))
+        wal.append("switch_result", i, dict(gid=np.int32(i),
+                                            results=[np.int32(5)]))
+        wal.append("ckpt", i, dict(reason="x", n_changed=np.int64(1)))
+    assert wal.records_appended == 12 and wal.records_templated == 0
+    assert all(r.hash == record_hash(r.prev, r.lsn, r.kind, r.tid, r.payload)
+               for r in wal)
+    wal.verify()
 
 
 # ===================================================================== #

@@ -53,14 +53,14 @@ OPS_PER_TXN = 4
 BATCH = 256
 
 
-def setup(seed=0, use_switch=True, n_switches=1, mode="auto"):
+def setup(seed=0, use_switch=True, n_switches=1):
     """Cluster + the loaded key/value universe (values = 3k + 7, so scan
     selectivity is controllable by value range)."""
     from dataclasses import replace
     cfg = replace(SW, n_switches=n_switches)
     keys = [key_of(i % N_NODES, i) for i in range(N_KEYS)]
     hi = build_hot_index([[(k, "W")] for k in keys], N_KEYS, cfg)
-    c = Cluster(N_NODES, cfg, hi, use_switch=use_switch, switch_mode=mode)
+    c = Cluster(N_NODES, cfg, hi, use_switch=use_switch)
     vals = {}
     for i, k in enumerate(keys):
         vals[k] = 3 * i + 7
@@ -104,16 +104,16 @@ def switch_served(c, batches):
     return out
 
 
-def equivalence(batches, vals, n_switches, mode):
-    """Cross-mode equivalence BEFORE timing: switch-served reads must
-    equal the store-served baseline's results AND the loaded truth."""
-    cs, keys, _ = setup(use_switch=True, n_switches=n_switches, mode=mode)
+def equivalence(batches, vals, n_switches):
+    """Equivalence BEFORE timing: switch-served reads must equal the
+    store-served baseline's results AND the loaded truth."""
+    cs, keys, _ = setup(use_switch=True, n_switches=n_switches)
     cb, _, _ = setup(use_switch=False)
     a = switch_served(cs, batches[:1])
     b = store_served(cb, batches[:1])
     truth = [[vals[k] for _, k, _ in t.ops] for t in batches[0]]
     assert a == b == truth, \
-        f"read tier diverged (N={n_switches}, mode={mode})"
+        f"read tier diverged (N={n_switches})"
     # and the pruned scan agrees with a host-side filter of the truth
     lo, hi = 100, 400
     want = sorted((k, v) for k, v in vals.items() if lo <= v <= hi)
@@ -200,7 +200,7 @@ def main():
     enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
-                    help="small CI smoke; still asserts cross-mode "
+                    help="small CI smoke; still asserts cross-shard "
                          "equivalence before timing")
     ap.add_argument("--out", default="BENCH_reads.json")
     args = ap.parse_args()
@@ -217,10 +217,9 @@ def main():
 
     _, keys, vals = setup()
     eq_batches = read_txns(keys, 1, seed=9)
-    for ns, mode in ((1, "auto"), (1, "pallas"), (2, "auto")):
-        equivalence(eq_batches, vals, ns, mode)
-    results["equivalence"] = {"checked": ["n1/auto", "n1/pallas",
-                                          "n2/auto"], "ok": True}
+    for ns in (1, 2):
+        equivalence(eq_batches, vals, ns)
+    results["equivalence"] = {"checked": ["n1/auto", "n2/auto"], "ok": True}
     print("  equivalence (switch == store == truth, + scan): OK")
 
     rp = bench_read_path(n_batches, reps)

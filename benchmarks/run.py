@@ -15,7 +15,7 @@ to artifacts/bench/.
   fig18  TPC-C latency breakdown + existing-optimization stack
   bench_adaptive  drifting hot set: static vs adaptive vs oracle placement
   bench_durability  recovery time vs checkpoint interval + priced failover
-  engine switch-engine execution modes (serial / affine / staged / pallas)
+  engine switch-engine execution modes (serial / affine / staged)
 """
 from __future__ import annotations
 
@@ -390,8 +390,6 @@ SUMMARY_HEADLINES = [
      "pipelined switch rounds vs per-txn (timing sim, PR 3)"),
     ("BENCH_adaptive.json", ("headline_adaptive_vs_oracle",),
      "adaptive vs oracle hot rate under drift (PR 4)"),
-    ("BENCH_hotpath.json", ("headline_async_speedup",),
-     "async hot path vs the PR 1 batched path (functional, PR 5)"),
     ("BENCH_durability.json", ("headline_recovery_speedup",),
      "bounded recovery: checkpointed vs full-WAL replay (PR 6)"),
     ("BENCH_multiswitch.json", ("headline_multiswitch_speedup",),
@@ -479,7 +477,7 @@ def engine_micro():
     p["reg"] = rng.integers(0, 4096, (B, K)).astype(np.int32)
     p["operand"] = rng.integers(-100, 100, (B, K)).astype(np.int32)
     rows = []
-    for mode in ("serial", "affine", "staged", "pallas"):
+    for mode in ("serial", "affine", "staged"):
         eng = SwitchEngine(cfg)
         eng.execute(p, mode=mode)  # compile
         t0 = time.time()

@@ -49,7 +49,6 @@ SEED = 0
 N_NODES = 8              # YCSBParams / SmallBankParams defaults
 N_YCSB = 4000            # YCSB-A txns per stream
 N_SMALLBANK = 3000
-N_PALLAS = 1000          # the pallas cluster runs the kernels per instr
 N_FOUR = 2000
 SAMPLE = 4000            # txns sampled offline to detect the hot set
 BATCH = 1024             # run_batch admission batch
@@ -268,27 +267,6 @@ def phase_reads(cfg, state):
                 read_dispatches=c.switch.read_dispatch_count)
 
 
-def phase_pallas(cfg, state):
-    """switch_mode="pallas": the switch_exec and gather kernels on the
-    write path and the gather kernel on the read path; identical results
-    and registers to the auto cluster on the same stream."""
-    p, hi, txns, loads, _, _ = state["ycsb"]
-    txns = txns[:N_PALLAS]
-    o = make_oracle(loads)
-    want = [o.apply_txn(t) for t in txns]
-    ca = make_cluster(cfg, hi, loads)
-    cp = make_cluster(cfg, hi, loads, switch_mode="pallas")
-    ra = run_stream(ca, txns, [256])
-    rp = run_stream(cp, txns, [256])
-    check(rp == ra, "pallas: client results differ from the auto cluster")
-    check_results(rp, want, "pallas")
-    check(np.array_equal(cp.switch.read_all(), ca.switch.read_all()),
-          "pallas: registers differ from the auto cluster")
-    check_state(cp, o, loads, "pallas")
-    reads_and_scans(cp, o, p, sorted(hi.placement.slot), "pallas reads")
-    return counts(cp)
-
-
 def phase_recover(cfg, state):
     """crash_switch_and_recover: registers rebuilt from the WALs and the
     checkpoint chain are byte-identical to the registers before."""
@@ -351,8 +329,7 @@ def phase_four_switches(cfg, state):
 
 
 ONE_CHIP = [("ycsb_a", phase_ycsb), ("smallbank", phase_smallbank),
-            ("reads_scans", phase_reads), ("pallas", phase_pallas),
-            ("crash_recover", phase_recover)]
+            ("reads_scans", phase_reads), ("crash_recover", phase_recover)]
 FOUR_CHIPS = [("four_switches", phase_four_switches)]
 
 

@@ -5,7 +5,8 @@ one packet per cycle, so pipelined execution of a batch equals the serial
 schedule in admission order.  Multi-pass packets hold the pipeline lock, so
 the serial order still equals admission order (§5.2).
 
-Two functional execution paths produce that serial-equivalent result:
+Three functional execution paths produce that serial-equivalent result;
+``_resolve_mode`` picks one per batch from its opcodes (rules below):
 
   serial  — a sequential loop over the flattened instruction stream, run
             on the batch's working set (its distinct slots), never the
@@ -21,9 +22,10 @@ Two functional execution paths produce that serial-equivalent result:
             the serial path (the paper similarly falls back to multi-pass
             for complex constraints).
 
-A Pallas kernel (kernels/switch_txn) implements the serial-chunk engine
-with VMEM-resident registers — the literal switch-pipeline analogue — and
-is validated against the serial engine in tests.
+  staged  — ADDP batches whose every source slot sits at an earlier
+            stage: stages run in order, each a per-register affine scan,
+            and ADDP operands forward from earlier stages' results (the
+            pipeline's cross-stage forwarding).
 
 Every executed transaction gets a globally-unique ID (GID) reflecting the
 serial order; GIDs drive WAL recovery in repro.db (paper §6.1).
@@ -69,8 +71,7 @@ Engine-mode dispatch rules (``mode="auto"``):
   otherwise                   -> affine  (fully vectorized scan)
 
 Explicit modes validate instead of silently mis-executing: ``affine``
-rejects CADD/ADDP, ``staged`` rejects CADD and unsafe ADDP, ``pallas``
-rejects ADDP.
+rejects CADD/ADDP, ``staged`` rejects CADD and unsafe ADDP.
 """
 from __future__ import annotations
 
@@ -539,9 +540,7 @@ class SwitchEngine:
             raise ValueError("staged engine forwards ADDP results from "
                              "earlier stages only; multipass ADDP packets "
                              "need the serial path")
-        if mode == "pallas" and has_addp:
-            raise ValueError("pallas kernel has no ADDP opcode; use serial")
-        if mode not in ("serial", "staged", "affine", "pallas"):
+        if mode not in ("serial", "staged", "affine"):
             raise ValueError(mode)
         return mode
 
@@ -611,25 +610,13 @@ class SwitchEngine:
         # the dispatch thread, chaining register state in FIFO order
         staged = self._stager.stage(pkts, idx, Bp, Mp)
         S, R = self.cfg.n_stages, self.cfg.regs_per_stage
-        if mode == "pallas":
-            def job():
-                from repro.kernels.switch_txn import ops as ktx
-                fused = self._put(staged)
-                regs, res, ok = ktx.switch_exec(self.registers, fused[0],
-                                                fused[1], fused[2],
-                                                fused[3])
-                compact = ktx.gather_results(res,
-                                             fused[4].reshape(-1)[:Mp])
-                self.registers = regs
-                return regs, res, ok, compact
-        else:
-            fn = _compiled_engine(mode, S, R, Bp, K, Mp, self._device)
+        fn = _compiled_engine(mode, S, R, Bp, K, Mp, self._device)
 
-            def job():
-                fused = self._put(staged)
-                regs, res, ok, compact = fn(self.registers, fused)
-                self.registers = regs
-                return regs, res, ok, compact
+        def job():
+            fused = self._put(staged)
+            regs, res, ok, compact = fn(self.registers, fused)
+            self.registers = regs
+            return regs, res, ok, compact
 
         self.dispatch_count += 1
         self.h2d_bytes += staged.nbytes
@@ -645,8 +632,8 @@ class SwitchEngine:
         return PendingBatch(res, ok, compact, gids, B, K, base, idx, mode,
                             engine=self)
 
-    def execute_reads(self, rp: ReadPacket, mode: str = "auto",
-                      defer: bool = False) -> PendingRead:
+    def execute_reads(self, rp: ReadPacket, defer: bool = False
+                      ) -> PendingRead:
         """The switch-served read path: answer a READ-only packet batch
         straight from the resident device registers, skipping everything
         the write path needs — no GID, no WAL entry, no pipeline lock, no
@@ -668,17 +655,12 @@ class SwitchEngine:
         idx = np.zeros(Mp, np.int32)
         idx[:M] = rp.flat_idx(self.cfg)
         S, R = self.cfg.n_stages, self.cfg.regs_per_stage
-        if mode == "pallas":
-            def job():
-                from repro.kernels.switch_txn import ops as ktx
-                return ktx.gather_results(self.registers, self._put(idx))
-        else:
-            fn = _compiled_reader(S, R, Mp, self._device)
+        fn = _compiled_reader(S, R, Mp, self._device)
 
-            def job():
-                # reads self.registers AT EXECUTION time on the dispatch
-                # thread — FIFO chaining puts it after every earlier write
-                return fn(self.registers, self._put(idx))
+        def job():
+            # reads self.registers AT EXECUTION time on the dispatch
+            # thread — FIFO chaining puts it after every earlier write
+            return fn(self.registers, self._put(idx))
 
         self.read_dispatch_count += 1
         out, fut = self._submit(job, defer)
@@ -943,8 +925,7 @@ class ShardedSwitchEngine:
             ok[k] = bool(pb.ok_np()[0, 0])
         return res, ok
 
-    def execute_reads(self, rp: ReadPacket, mode: str = "auto",
-                      defer: bool = False):
+    def execute_reads(self, rp: ReadPacket, defer: bool = False):
         """Sharded read path: split the READ-only batch by shard, gather
         each shard's values concurrently on its own plane (its own device
         + dispatch thread), scatter back to key order on drain.  Reads
@@ -952,7 +933,7 @@ class ShardedSwitchEngine:
         cross-shard barrier exists — unlike writes, a 'cross-shard read'
         cannot happen (each key lives on exactly one shard)."""
         if self.n == 1:
-            return self.planes[0].execute_reads(rp, mode=mode, defer=defer)
+            return self.planes[0].execute_reads(rp, defer=defer)
         M = rp.n
         if M == 0:
             return PendingRead(np.zeros(0, np.int32), 0)
@@ -965,8 +946,8 @@ class ShardedSwitchEngine:
                              stage=rp.stage[pos], reg=rp.reg[pos])
             # defer per shard even on a sync call: the shards gather in
             # parallel; _MergedRead's materialization joins them in order
-            pr = self.planes[sw].execute_reads(
-                sub, mode=mode, defer=self.async_dispatch)
+            pr = self.planes[sw].execute_reads(sub,
+                                               defer=self.async_dispatch)
             parts.append((pos, pr))
         handle = _MergedRead(M, parts)
         if not defer and self.async_dispatch:

@@ -230,7 +230,7 @@ class Cluster:
     def __init__(self, n_nodes: int, switch_cfg: SwitchConfig,
                  hot_index: Optional[HotIndex] = None,
                  protocol: str = NO_WAIT, use_switch: bool = True,
-                 switch_mode: str = "auto", async_hot: bool = False,
+                 async_hot: bool = False,
                  max_inflight: int = 2, wal_mode: str = "segmented",
                  wal_segment_size: int = DEFAULT_SEGMENT_SIZE,
                  checkpoint_interval: int = 0, standby: bool = False,
@@ -249,7 +249,6 @@ class Cluster:
         self.switch = self._fresh_engine()
         self.hot_index = hot_index          # setter replicates to nodes
         self.use_switch = use_switch and hot_index is not None
-        self.switch_mode = switch_mode
         self._ts = 0
         # telemetry plane (repro.obs): on by default, pinned zero-cost —
         # the registry/tracer never touch engine state, RNG or WALs, so
@@ -357,7 +356,7 @@ class Cluster:
             txn = Txn("load", [(WRITE, key, value)], node_of(key), tid=tid)
             pkt, meta = build_packets([txn], self.hot_index, self.switch_cfg)
             node.log("switch_send", tid, ops=list(txn.ops))
-            pb = self.switch.execute_batch(pkt, meta, mode=self.switch_mode)
+            pb = self.switch.execute_batch(pkt, meta)
             node.log("switch_result", tid, gid=int(pb.gids[0]),
                      results=pb.results_np()[0, :1].tolist())
             self._note_sends(1)
@@ -464,27 +463,17 @@ class Cluster:
                 klass=kind).observe(time.perf_counter() - t0)
         return out
 
-    def _validate_mode(self, flags: dict):
-        """Reject an explicit switch_mode the packets cannot run under
-        BEFORE any switch_send is logged — a send entry counts as committed
-        in recovery, so it must never precede a refused dispatch."""
-        if self.switch_mode != "auto":
-            SwitchEngine._resolve_mode(self.switch_mode, flags["has_cadd"],
-                                       flags["has_addp"],
-                                       flags["addp_unsafe"])
-
     # hot: switch-only, abort-free, no coordination (paper §5)
     def _run_hot(self, txn: Txn, tr=None):
         home = self.nodes[txn.home]
         with _span(tr, "packet-build"):
             pkt, meta = build_packets([txn], self.hot_index, self.switch_cfg)
-        self._validate_mode(meta)
         t0 = tr.clock() if tr is not None else 0.0
         home.log("switch_send", txn.tid, ops=list(txn.ops))
         if tr is not None:
             tr.add_span("wal-send", t0, tr.clock())
         with _span(tr, "dispatch"):
-            pb = self.switch.execute_batch(pkt, meta, mode=self.switch_mode)
+            pb = self.switch.execute_batch(pkt, meta)
         with _span(tr, "drain"):
             res = pb.results_np()
             pb.release()
@@ -525,12 +514,6 @@ class Cluster:
         for any of them, so any serialization of in-flight txns is
         recoverable (paper §A.3); but unlike the per-txn loop the replayed
         registers may then differ from the pre-crash state.
-
-        One divergence: under an *explicit* ``switch_mode``, a group is
-        validated (and rejected) as a unit before any send is logged,
-        whereas the per-txn loop would commit the compatible prefix before
-        raising on the first incompatible txn.  ``auto`` mode never
-        rejects, so the equivalence contract is unconditional there.
 
         Returns the per-txn result lists in admission order.  A txn that
         exhausted its retries holds the falsy ``GAVE_UP`` sentinel —
@@ -643,8 +626,8 @@ class Cluster:
                 if det is not None:
                     det.release(txn.tid)
             except Exception:
-                # non-Abort failures (e.g. a rejected explicit switch_mode)
-                # must not leak this txn's locks while propagating
+                # non-Abort failures must not leak this txn's locks while
+                # propagating
                 for n in self.nodes:
                     n.release_all(txn.tid)
                 if det is not None:
@@ -676,20 +659,18 @@ class Cluster:
     def _flush_hot_group(self, pending: List[Tuple[int, Txn]],
                          results: List[Optional[list]], tr=None):
         """Commit all buffered hot txns in as few switch dispatches as the
-        engine allows.  Under ``auto`` mode a single multipass-ADDP
-        ("unsafe") txn would demote the whole group to the serial engine
-        (``_resolve_mode``); instead the group is split at unsafe txns —
-        contiguous safe runs stay on the vectorized path, unsafe runs take
-        the serial path — with sub-groups dispatched in admission order,
-        so results, register state and GIDs are unchanged.  Explicit modes
-        keep the single-dispatch, validate-as-a-unit contract."""
+        engine allows.  A single multipass-ADDP ("unsafe") txn would demote
+        the whole group to the serial engine (``_resolve_mode``); instead
+        the group is split at unsafe txns — contiguous safe runs stay on
+        the vectorized path, unsafe runs take the serial path — with
+        sub-groups dispatched in admission order, so results, register
+        state and GIDs are unchanged."""
         if not pending:
             return
         with _span(tr, "packet-build"):
             pkts, meta = build_packets([t for _, t in pending],
                                        self.hot_index, self.switch_cfg)
-            split = self.switch_mode == "auto" and meta["addp_unsafe"] \
-                and len(pending) > 1
+            split = meta["addp_unsafe"] and len(pending) > 1
             unsafe = addp_unsafe_rows(pkts) if split else None
         if split:
             lo = 0
@@ -719,7 +700,6 @@ class Cluster:
         with _span(tr, "packet-build"):
             pkts, meta = prebuilt or build_packets(group, self.hot_index,
                                                    self.switch_cfg)
-        self._validate_mode(meta)
         t0 = tr.clock() if tr is not None else 0.0
         for t in group:
             # list(t.ops): ops tuples are immutable, no need to repack
@@ -731,14 +711,7 @@ class Cluster:
         # unknown-GID entries that recovery must replay
         self._fault("mid_group_dispatch", tids=[t.tid for t in group])
         with _span(tr, "dispatch"):
-            if self.async_hot:
-                pb = self.switch.execute_batch(pkts, meta,
-                                               mode=self.switch_mode,
-                                               defer=True)
-            else:
-                # 3-arg call kept for monkeypatch/spy compatibility
-                pb = self.switch.execute_batch(pkts, meta,
-                                               mode=self.switch_mode)
+            pb = self.switch.execute_batch(pkts, meta, defer=self.async_hot)
         multipass = int(np.count_nonzero(pkts["is_multipass"][:len(group)]))
         self.stats["commits"] += len(group)
         if multipass:
@@ -896,12 +869,6 @@ class Cluster:
                       tid=txn.tid)
         if tr is not None:
             tr.add_span("warm-split", t0, tr.clock())
-        # an explicit switch_mode that rejects the hot sub-txn must fail
-        # BEFORE the cold part takes locks and applies/logs its writes
-        if self.switch_mode != "auto":
-            _, meta = build_packets([hot_txn], self.hot_index,
-                                    self.switch_cfg)
-            self._validate_mode(meta)
         t0 = tr.clock() if tr is not None else 0.0
         try:
             cold_res = self._exec_on_nodes(cold_txn, ts=self._ts)
@@ -1032,7 +999,7 @@ class Cluster:
         pkts, meta = build_packets(group, self.hot_index, self.switch_cfg)
         for t in group:
             self.nodes[t.home].log("switch_send", t.tid, ops=list(t.ops))
-        pb = self.switch.execute_batch(pkts, meta, mode=self.switch_mode)
+        pb = self.switch.execute_batch(pkts, meta)
         res = pb.results_np()
         for b, t in enumerate(group):
             self.nodes[t.home].log("switch_result", t.tid,
@@ -1149,7 +1116,7 @@ class Cluster:
         if len(hot_pos):
             rp = build_read_packets(keys[hot_pos], self.hot_index,
                                     self.switch_cfg)
-            pr = self.switch.execute_reads(rp, mode=self._read_mode())
+            pr = self.switch.execute_reads(rp)
             out[hot_pos] = pr.values_np()
             self.stats["switch_reads"] += len(hot_pos)
         for i in np.flatnonzero(~hot):
@@ -1160,12 +1127,6 @@ class Cluster:
                 H_READ_BATCH, help="read_batch wall time").observe(
                     time.perf_counter() - t0)
         return [int(v) for v in out]
-
-    def _read_mode(self) -> str:
-        # READ gathers have no CADD/multipass constraints: any engine mode
-        # can serve them.  "pallas" keeps the faithful-execution kernels
-        # in the loop; every other mode uses the AOT-cached jit gather.
-        return "pallas" if self.switch_mode == "pallas" else "auto"
 
     def scan(self, lo: int, hi: int, keys=None, limit: Optional[int] = None):
         """Range-predicate scan with switch-side pruning: filter value in

@@ -1,5 +1,5 @@
-"""jit'd wrapper around the switch_txn Pallas kernel: pads the instruction
-stream, flattens (stage, reg) -> global slot, restores [B, K] shapes."""
+"""jit'd wrappers around the switch_txn Pallas kernels: the result gather
+and the scan-prune / top-k queries over the register file."""
 from __future__ import annotations
 
 import functools
@@ -8,29 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.switch_txn.switch_txn import (result_gather_call,
-                                                 scan_prune_call,
-                                                 switch_txn_call)
-
-NOP = 0
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def switch_exec(registers, op, stage, reg, val, chunk=1024, interpret=None):
-    """registers: [S, R] int32; op/stage/reg/val: [B, K].
-
-    Returns (new_registers [S,R], results [B,K], ok [B,K] bool)."""
-    S, R = registers.shape
-    B, K = op.shape
-    n = B * K
-    g = (stage * R + reg).reshape(-1)
-    # the kernel NOP-pads any stream length to the next chunk boundary;
-    # capping chunk at n keeps small batches from running a mostly-NOP chunk
-    regs, res, ok = switch_txn_call(registers.reshape(-1), op.reshape(-1),
-                                    g, val.reshape(-1),
-                                    chunk=min(chunk, max(n, 1)),
-                                    interpret=interpret)
-    return (regs.reshape(S, R), res.reshape(B, K),
-            ok.reshape(B, K).astype(bool))
+                                                 scan_prune_call)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

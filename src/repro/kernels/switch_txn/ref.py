@@ -1,42 +1,7 @@
-"""Pure-jnp oracle for the switch-transaction kernel: serial execution of
-the flattened instruction stream (identical semantics to
-repro.core.engine._serial_engine, restated here so the kernel package is
-self-contained)."""
+"""Plain-numpy oracles for the scan kernels of switch_txn."""
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-
-NOP, READ, WRITE, ADD, CADD = 0, 1, 2, 3, 4
-
-
-def switch_exec_ref(registers, op, stage, reg, val):
-    """registers: [S, R] int32; op/stage/reg/val: [B, K] int32.
-    Returns (new_registers, results [B,K], ok [B,K])."""
-    S, R = registers.shape
-    B, K = op.shape
-    g = (stage * R + reg).reshape(-1)
-    flat = registers.reshape(-1)
-
-    def step(regs, x):
-        o, gi, v = x
-        cur = regs[gi]
-        post = cur + v
-        cadd_ok = post >= 0
-        new = jnp.where(o == WRITE, v,
-              jnp.where(o == ADD, post,
-              jnp.where((o == CADD) & cadd_ok, post, cur)))
-        res = jnp.where(o == READ, cur, jnp.where(o == NOP, 0, new))
-        ok = jnp.where(o == CADD, cadd_ok, True)
-        regs = regs.at[gi].set(jnp.where(o == NOP, cur, new))
-        return regs, (res, ok)
-
-    flat, (res, ok) = jax.lax.scan(
-        step, flat, (op.reshape(-1), g, val.reshape(-1)))
-    return flat.reshape(S, R), res.reshape(B, K), ok.reshape(B, K)
-
-
-# ------------------------------------------------- scan-pruning oracles --
+import numpy as np
 
 AGG_MIN_EMPTY = 2147483647        # int32 identities for empty scans —
 AGG_MAX_EMPTY = -2147483648       # must match switch_txn.scan_prune_call
@@ -48,7 +13,6 @@ def scan_prune_ref(src, lo, hi, cap):
 
     Returns (vals [cap], idx [cap], agg [4]) with identical padding and
     empty-scan sentinels to the kernel."""
-    import numpy as np
     src = np.asarray(src, np.int32)
     pos = np.flatnonzero((src >= lo) & (src <= hi)).astype(np.int32)
     count = len(pos)
@@ -73,7 +37,6 @@ def scan_topk_ref(src, lo, hi, k):
     values, ties broken toward the lower stream position (lax.top_k's
     tie rule).  Returns (vals [k], idx [k], count); slots past ``count``
     hold the int32-min sentinel and whatever position sorted there."""
-    import numpy as np
     src = np.asarray(src, np.int32)
     masked = np.where((src >= lo) & (src <= hi), src,
                       np.int32(AGG_MAX_EMPTY))

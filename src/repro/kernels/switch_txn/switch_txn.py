@@ -1,37 +1,28 @@
-"""Pallas TPU kernels: the switch pipeline as a VMEM-resident register file.
+"""Pallas TPU kernels of the switch: the serial engine's loop, the
+result gather, and the scan-prune query.
 
 Hardware mapping (Tofino -> TPU):
-  * the MAU stages' SRAM register arrays live in ONE VMEM scratch buffer
-    for the whole kernel invocation, laid out as ``[rows, 128]`` int32
-    (one lane per register slot, slot ``g`` at row ``g // 128``, lane
-    ``g % 128``).  It is copied in from HBM at the first grid step and
-    back out at the last one; the HBM buffer is aliased input -> output,
-    so a donated register file is updated in place.  The scratch persists
-    across the sequential TPU grid, like stage SRAM persists across
-    packets;
-  * the packet stream (op / slot / operand) is blocked into SMEM tiles of
-    ``chunk`` instructions — scalar memory, where per-instruction scalar
-    reads are legal; grid steps execute in order, so instruction order ==
-    serial order == the switch's pipeline admission order;
-  * per instruction, one register row is loaded, the slot's value is
-    selected out of its lane, the opcode is applied on scalars (including
-    CADD, the P4 constrained-write the vectorized affine engine cannot
-    express), and the row is stored back with only that lane changed.
-    Results and success flags are scalar stores into SMEM output tiles.
-    Mosaic has no scalar store into VMEM, so every VMEM update is such a
-    masked whole-row store.
+  * index and value streams are blocked into SMEM tiles of ``chunk``
+    entries — scalar memory, where per-element scalar reads are legal;
+    grid steps execute in order, so stream order is preserved;
+  * anything addressed at random (a gather's source: the register file or
+    a result plane) lives in VMEM as ``[rows, 128]`` int32 (one lane per
+    slot, slot ``g`` at row ``g // 128``, lane ``g % 128``), copied in
+    from HBM at the first grid step.  Mosaic has no scalar load or store
+    on VMEM, so an element is read as a masked reduction of its row and
+    written as a masked whole-row store.
 
-The gather and the scan-prune kernels use the same two layouts: index and
-value streams in SMEM, anything addressed at random in ``[rows, 128]``
-VMEM.  The serial engine's loop (``serial_ws_call``) is all SMEM: it runs
-on the batch's working set, one word per distinct slot (``core/engine``
+The serial engine's loop (``serial_ws_call``) is all SMEM: it runs on
+the batch's working set, one word per distinct slot (``core/engine``
 gathers it from the register file and scatters it back), so every step
-is scalar loads and stores.  VMEM use is the register file (5 MiB at the
-default 20 x 65,536 configuration) plus the compacted scan output; ``_vmem_limit`` sets the
-compiler's scoped VMEM limit from those sizes.
+is scalar loads and stores, including CADD, the P4 constrained write the
+vectorized engines cannot express.  VMEM use is the gather source (the
+register file is 5 MiB at the default 20 x 65,536 configuration) plus
+the compacted scan output; ``_vmem_limit`` sets the compiler's scoped
+VMEM limit from those sizes.
 
-This is the faithful-execution path; the affine-scan engine (core/engine)
-is the vectorized path.  Both are validated against ref.py.
+The scan kernels are validated against ref.py, the serial engine
+against the tests' serial loop reference.
 """
 from __future__ import annotations
 
@@ -81,10 +72,10 @@ def _as_rows(flat):
     return flat.reshape(-1, LANES)
 
 
-def _pad_stream(x, chunk, fill=0):
+def _pad_stream(x, chunk):
     pad = (-x.shape[0]) % chunk
     if pad:
-        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
     return x
 
 
@@ -101,78 +92,6 @@ def _smem_whole(n):
 
 
 _HBM = pl.BlockSpec(memory_space=pl.ANY)
-
-
-# ------------------------------------------------------ switch RMW kernel --
-
-def _kernel(op_ref, g_ref, val_ref, regs_hbm, regs_out_hbm, res_ref, ok_ref,
-            regs_v, *, chunk, n_slots):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _load():
-        pltpu.sync_copy(regs_hbm, regs_v)
-
-    lanes = _lanes()
-
-    def body(i, _):
-        o = op_ref[i]
-        g = jnp.minimum(g_ref[i], n_slots - 1)
-        v = val_ref[i]
-        row_ix = pl.ds(g // LANES, 1)
-        row = regs_v[row_ix, :]
-        hit = lanes == g % LANES
-        cur = jnp.sum(jnp.where(hit, row, 0))
-        post = cur + v
-        cadd_ok = post >= 0
-        # READ and NOP leave new == cur, so the row store is a no-op there
-        new = jnp.where(o == WRITE, v,
-              jnp.where(o == ADD, post,
-              jnp.where((o == CADD) & cadd_ok, post, cur)))
-        regs_v[row_ix, :] = jnp.where(hit, new, row)
-        res_ref[i] = jnp.where(o == READ, cur, jnp.where(o == NOP, 0, new))
-        ok_ref[i] = jnp.where(o == CADD, cadd_ok, True).astype(jnp.int32)
-        return ()
-
-    jax.lax.fori_loop(0, chunk, body, ())
-
-    @pl.when(step == pl.num_programs(0) - 1)
-    def _store():
-        pltpu.sync_copy(regs_v, regs_out_hbm)
-
-
-def switch_txn_call(registers_flat, op, g, val, *, chunk=1024,
-                    interpret=None):
-    """registers_flat: [n_slots] int32; op/g/val: [N] int32, any N >= 1.
-
-    Streams that are not a multiple of ``chunk`` are padded with NOP
-    instructions up to the next chunk boundary (NOPs leave registers and
-    results untouched); the padded tail is sliced off before returning.
-
-    Returns (new_registers [n_slots], results [N], ok [N] int32)."""
-    n_slots = registers_flat.shape[0]
-    n = op.shape[0]
-    op = _pad_stream(op, chunk, NOP)
-    g = _pad_stream(g, chunk)
-    val = _pad_stream(val, chunk)
-    regs = _as_rows(registers_flat)
-    stream = _smem_stream(chunk)
-    regs, res, ok = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, n_slots=n_slots),
-        grid=(op.shape[0] // chunk,),
-        in_specs=[stream, stream, stream, _HBM],
-        out_specs=[_HBM, stream, stream],
-        out_shape=[
-            jax.ShapeDtypeStruct(regs.shape, jnp.int32),
-            jax.ShapeDtypeStruct(op.shape, jnp.int32),
-            jax.ShapeDtypeStruct(op.shape, jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM(regs.shape, jnp.int32)],
-        input_output_aliases={3: 0},
-        compiler_params=_params(regs.size * 4),
-        interpret=_resolve(interpret),
-    )(op, g, val, regs)
-    return regs.reshape(-1)[:n_slots], res[:n], ok[:n]
 
 
 # -------------------------------------------- serial working-set kernel --
@@ -249,12 +168,11 @@ def _gather_kernel(idx_ref, src_hbm, out_ref, src_v, *, chunk, n_src):
 def result_gather_call(src, idx, *, chunk=1024, interpret=None):
     """Result-compaction gather: out[i] = src[clip(idx[i], 0, n-1)].
 
-    The async hot path's result plane ships only the compacted READ-class
-    results device -> host; this kernel is the gather step for the pallas
-    engine mode (the jit engines fuse an equivalent ``jnp.take`` into
-    their compiled call).  ``idx`` is padded by the packet stager to a
-    power-of-two bucket; pad entries point at slot 0 and are sliced off
-    by the caller, so clamping (not masking) is sufficient.
+    The gather step of the scan queries (``ops.scan_prune`` /
+    ``ops.scan_topk``): it pulls the queried slots out of the register
+    file (the engines fuse an equivalent ``jnp.take`` into their compiled
+    call instead).  Pad entries of ``idx`` point at slot 0 and are sliced
+    off by the caller, so clamping (not masking) is sufficient.
 
     src: [N] int32; idx: [M] int32, any M >= 1.  Returns [M] int32."""
     n_src = src.shape[0]
@@ -285,7 +203,7 @@ def _scan_prune_kernel(lo_ref, hi_ref, src_ref, vals_ref, idx_ref, agg_ref,
                        *, chunk, n, cap):
     """Predicate scan + on-device compaction over a value stream.
 
-    Walks the stream in order (sequential grid, like the RMW kernel);
+    Walks the stream in order (sequential grid);
     every in-range element bumps the aggregate lanes (count/sum/min/max)
     and — while the output buffer has room — is appended to the compacted
     (value, position) outputs, which stay VMEM-resident for the whole

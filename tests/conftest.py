@@ -42,6 +42,24 @@ def _workload_seed_determinism():
     yield
 
 
+def force_engine(monkeypatch, mode):
+    """Make every switch engine resolve ``"auto"`` to ``mode`` for the
+    rest of the test: the batch's engine is then ``mode``, still
+    validated by the real ``SwitchEngine._resolve_mode`` (a batch ``mode``
+    cannot run raises).  Patching the class also covers the engines a
+    cluster rebuilds after a recovery.  ``mode="auto"`` changes nothing."""
+    from repro.core.engine import SwitchEngine
+    if mode == "auto":
+        return
+    resolve = SwitchEngine._resolve_mode
+
+    def forced(m, has_cadd, has_addp, addp_unsafe):
+        return resolve(mode if m == "auto" else m, has_cadd, has_addp,
+                       addp_unsafe)
+
+    monkeypatch.setattr(SwitchEngine, "_resolve_mode", staticmethod(forced))
+
+
 def pytest_addoption(parser):
     parser.addoption(
         "--runslow", action="store_true", default=False,

@@ -12,10 +12,12 @@ import pytest
 from repro.core.engine import SwitchEngine, _bucket
 from repro.core.hotset import build_hot_index
 from repro.core.layout import random_layout
-from repro.core.packets import (ADDP, CADD, SwitchConfig, build_packets,
+from repro.core.packets import (ADD, ADDP, SwitchConfig, build_packets,
                                 empty_packets)
 from repro.db.dbms import Cluster
-from repro.workloads import smallbank, ycsb
+from repro.workloads import smallbank, tpcc, ycsb
+
+from conftest import force_engine
 
 SW = SwitchConfig(n_stages=16, regs_per_stage=512, max_instrs=16)
 
@@ -42,8 +44,8 @@ def _smallbank(n=240, no_addp=False):
     return txns, hi, loads
 
 
-def _make_cluster(hi, loads, n_nodes, mode):
-    c = Cluster(n_nodes, SW, hi, use_switch=True, switch_mode=mode)
+def _make_cluster(hi, loads, n_nodes):
+    c = Cluster(n_nodes, SW, hi, use_switch=True)
     for k, v in loads:
         c.load(k, v)
     c.snapshot_offload()
@@ -63,10 +65,9 @@ def _warm_syncs(c, txns, batch_size):
     return n
 
 
-def _assert_equivalent(txns, hi, loads, n_nodes=4, mode="auto",
-                       batch_size=64):
-    c1 = _make_cluster(hi, loads, n_nodes, mode)
-    c2 = _make_cluster(hi, loads, n_nodes, mode)
+def _assert_equivalent(txns, hi, loads, n_nodes=4, batch_size=64):
+    c1 = _make_cluster(hi, loads, n_nodes)
+    c2 = _make_cluster(hi, loads, n_nodes)
     r1 = [c1.run(copy.deepcopy(t)) for t in txns]
     r2 = []
     for i in range(0, len(txns), batch_size):
@@ -104,43 +105,36 @@ def _assert_equivalent(txns, hi, loads, n_nodes=4, mode="auto",
     return c1, c2
 
 
-@pytest.mark.parametrize("mode", ["auto", "serial", "affine",
-                                  pytest.param("staged",
-                                               marks=pytest.mark.slow),
-                                  "pallas"])
-def test_ycsb_batched_equals_per_txn(mode):
+@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged"])
+def test_ycsb_batched_equals_per_txn(mode, monkeypatch):
+    force_engine(monkeypatch, mode)
     txns, hi, loads = _ycsb()
-    c1, c2 = _assert_equivalent(txns, hi, loads, mode=mode)
+    c1, c2 = _assert_equivalent(txns, hi, loads)
     assert c1.stats["hot"] > 0 and c1.stats["cold"] > 0
 
 
-@pytest.mark.parametrize("mode", ["auto", "serial", "affine",
-                                  pytest.param("staged",
-                                               marks=pytest.mark.slow),
-                                  "pallas"])
-def test_ycsb_warm_and_multipass_batches(mode):
+@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged"])
+def test_ycsb_warm_and_multipass_batches(mode, monkeypatch):
     """Small hot index -> warm txns; random layout -> multipass packets."""
+    force_engine(monkeypatch, mode)
     txns, hi, loads = _ycsb(top_k=40, layout_fn=random_layout)
-    c1, c2 = _assert_equivalent(txns, hi, loads, mode=mode)
+    c1, c2 = _assert_equivalent(txns, hi, loads)
     assert c1.stats["warm"] > 0
     assert c1.stats["multipass"] > 0
 
 
-@pytest.mark.parametrize("mode", ["auto", "serial"])
-def test_smallbank_batched_equals_per_txn(mode):
+@pytest.mark.parametrize("mode,no_addp", [("auto", False),
+                                          ("serial", False),
+                                          ("auto", True)],
+                         ids=["auto", "serial", "auto-no_addp"])
+def test_smallbank_batched_equals_per_txn(mode, no_addp, monkeypatch):
     """Full SmallBank mix: CADD constraints, ADDP read-dependent writes,
-    warm txns."""
-    txns, hi, loads = _smallbank()
-    c1, _ = _assert_equivalent(txns, hi, loads, n_nodes=2, mode=mode)
+    warm txns; and the same mix with the ADDP txns left out."""
+    force_engine(monkeypatch, mode)
+    txns, hi, loads = _smallbank(no_addp=no_addp)
+    c1, _ = _assert_equivalent(txns, hi, loads, n_nodes=2,
+                               batch_size=50 if no_addp else 64)
     assert c1.stats["hot"] > 0
-
-
-def test_smallbank_pallas_mode():
-    """Pallas path on the CADD-bearing mix (ADDP excluded: the kernel has
-    no ADDP opcode and validates against it)."""
-    txns, hi, loads = _smallbank(no_addp=True)
-    _assert_equivalent(txns, hi, loads, n_nodes=2, mode="pallas",
-                       batch_size=50)
 
 
 def test_build_packets_matches_per_txn_builder():
@@ -177,10 +171,63 @@ def test_build_packets_empty_and_metadata():
                                   [len(t.ops) for t in hot])
 
 
+def _family(name):
+    """A short stream of a workload family the benchmark serves, at a
+    small plane: (txns, hot index, loads, nodes, run_batch size)."""
+    if name == "ycsb":
+        txns, hi, loads = _ycsb()
+        return txns, hi, loads, 4, 64
+    if name == "smallbank":
+        # the deployment's 8 nodes with 10 hot accounts each
+        p = smallbank.SmallBankParams(accounts_per_node=1000)
+        sample = smallbank.generate(np.random.default_rng(0), 4000, p)
+        hot = smallbank.hot_keys(p)
+        hi = build_hot_index(smallbank.traces(sample), len(hot), SW)
+        txns = smallbank.generate(np.random.default_rng(1), 256, p)
+        return txns, hi, [(k, 100) for k in hot], 8, 256
+    p = tpcc.TPCCParams(n_nodes=2, n_warehouses=2)
+    hot = tpcc.hot_keys(p)
+    hi = build_hot_index([[(k, ADD) for k in hot]], len(hot), SW)
+    txns = tpcc.generate(np.random.default_rng(11), 64, p)
+    keys = {k for t in txns for k in t.keys()} | set(hot)
+    init = tpcc.initial_values(np.random.default_rng(12), sorted(keys))
+    return txns, hi, sorted(init.items()), 2, 64
+
+
+# the engines each family's stream dispatches on under auto
+FAMILY_ENGINES = {"ycsb": {"affine"},
+                  "smallbank": {"serial", "staged", "affine"},
+                  "tpcc": {"affine"}}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_ENGINES))
+def test_auto_runs_the_engines_each_family_calls_for(family, monkeypatch):
+    """``auto`` picks each hot group's engine from its opcodes: YCSB's
+    READ / WRITE groups run on affine; SmallBank's CADD groups on serial,
+    its ADDP groups without CADD on staged, the rest on affine; TPC-C's
+    warm txns on affine, one txn per dispatch."""
+    txns, hi, loads, n_nodes, batch = _family(family)
+    c = _make_cluster(hi, loads, n_nodes)
+    seen = []
+    execute_batch = SwitchEngine.execute_batch
+
+    def spy(self, *a, **kw):
+        pb = execute_batch(self, *a, **kw)
+        seen.append((pb.mode, pb.B))
+        return pb
+
+    monkeypatch.setattr(SwitchEngine, "execute_batch", spy)
+    for i in range(0, len(txns), batch):
+        c.run_batch([copy.deepcopy(t) for t in txns[i:i + batch]])
+    assert {m for m, _ in seen} == FAMILY_ENGINES[family]
+    if family == "tpcc":
+        assert {b for _, b in seen} == {1}
+
+
 def test_one_dispatch_per_hot_group():
     """A batch of hot-only txns commits in exactly ONE engine dispatch."""
     txns, hi, loads = _ycsb(n=600)
-    c = _make_cluster(hi, loads, 4, "auto")
+    c = _make_cluster(hi, loads, 4)
     hot = [t for t in txns if c.classify(t) == "hot"][:256]
     assert len(hot) == 256
     before = c.switch.dispatch_count
@@ -189,7 +236,7 @@ def test_one_dispatch_per_hot_group():
     assert c.stats["commits"] == 256
     assert all(r is not None for r in res)
     # per-txn loop pays 256 dispatches for the same work
-    c2 = _make_cluster(hi, loads, 4, "auto")
+    c2 = _make_cluster(hi, loads, 4)
     for t in hot:
         c2.run(copy.deepcopy(t))
     assert c2.switch.dispatch_count == 256
@@ -220,14 +267,15 @@ def _interleaved_unsafe(arrangement):
 @pytest.mark.parametrize("mode", ["auto", "serial"])
 @pytest.mark.parametrize("arrangement",
                          ["USSU", "SSUSS", "USSUSSSU", "UUSSU"])
-def test_group_split_equals_per_txn(arrangement, mode):
+def test_group_split_equals_per_txn(arrangement, mode, monkeypatch):
     """A hot group with multipass-ADDP txns at head/middle/tail matches the
     per-txn loop exactly — results, registers, GIDs, WAL recovery — in
-    every mode that can run such packets (auto splits; serial runs the
-    whole group)."""
+    every mode that can run such packets (the group splits at unsafe txns
+    either way; auto runs the safe parts on affine, serial runs every
+    part on serial)."""
+    force_engine(monkeypatch, mode)
     txns, hi, loads = _interleaved_unsafe(arrangement)
-    _assert_equivalent(txns, hi, loads, n_nodes=1, mode=mode,
-                       batch_size=len(txns))
+    _assert_equivalent(txns, hi, loads, n_nodes=1, batch_size=len(txns))
 
 
 def test_group_split_keeps_safe_runs_vectorized():
@@ -236,17 +284,15 @@ def test_group_split_keeps_safe_runs_vectorized():
     engine, unsafe runs on the serial oracle."""
     arrangement = "USSUSSSU"                       # runs: U|SS|U|SSS|U
     txns, hi, loads = _interleaved_unsafe(arrangement)
-    c = _make_cluster(hi, loads, 1, "auto")
+    c = _make_cluster(hi, loads, 1)
     d0 = c.switch.dispatch_count           # fixture loads dispatch too
     modes = []
     orig = c.switch.execute_batch
 
-    def spy(pkts, meta=None, mode="auto"):
-        from repro.core.packets import scan_flags
-        m = meta if meta is not None else scan_flags(pkts)
-        modes.append(SwitchEngine._resolve_mode(
-            mode, m["has_cadd"], m["has_addp"], m["addp_unsafe"]))
-        return orig(pkts, meta, mode)
+    def spy(pkts, meta=None, **kw):
+        pb = orig(pkts, meta, **kw)
+        modes.append(pb.mode)
+        return pb
 
     c.switch.execute_batch = spy
     res = c.run_batch(txns)
@@ -254,50 +300,11 @@ def test_group_split_keeps_safe_runs_vectorized():
     assert c.switch.dispatch_count - d0 == 5       # runs, not 8 txns
     assert modes == ["serial", "affine", "serial", "affine", "serial"]
     # per-txn world pays one dispatch per txn
-    c2 = _make_cluster(hi, loads, 1, "auto")
+    c2 = _make_cluster(hi, loads, 1)
     d0 = c2.switch.dispatch_count
     for t in _interleaved_unsafe(arrangement)[0]:
         c2.run(t)
     assert c2.switch.dispatch_count - d0 == len(arrangement)
-
-
-@pytest.mark.parametrize("mode", ["affine", "staged", "pallas"])
-def test_group_with_unsafe_rejected_as_unit_under_explicit_mode(mode):
-    """Explicit modes that cannot run multipass ADDP reject the whole
-    group before any switch_send is logged."""
-    txns, hi, loads = _interleaved_unsafe("SSU")
-    c = _make_cluster(hi, loads, 1, mode)
-    # fixture loads are themselves logged writes (so failover can recover
-    # them) — only entries appended by the rejected batch count
-    n0 = len(c.nodes[0].wal)
-    with pytest.raises(ValueError):
-        c.run_batch(txns)
-    assert not any(e.kind in ("switch_send", "switch_result")
-                   for e in list(c.nodes[0].wal)[n0:])
-
-
-def test_rejected_mode_fails_before_side_effects():
-    """An explicit switch_mode the hot sub-txn cannot run under must fail
-    before the warm txn's cold part takes locks or applies writes — and
-    must never leave phantom WAL entries or leaked locks."""
-    from repro.core.packets import WRITE
-    from repro.db.txn import Txn, key_of
-    hi = build_hot_index([[(key_of(0, 0), CADD)]], 1, SW)
-    c = Cluster(1, SW, hi, use_switch=True, switch_mode="affine")
-    c.load(key_of(0, 0), 100)
-    cold_key = key_of(0, 500)
-    warm = Txn("w", [(WRITE, cold_key, 5), (CADD, key_of(0, 0), -1)], 0)
-    # the load itself is a logged write; only post-load entries count
-    n0 = len(c.nodes[0].wal)
-    with pytest.raises(ValueError):
-        c.run(warm)
-    assert c.nodes[0].locks == {}
-    assert not any(e.kind in ("write", "switch_send", "commit")
-                   for e in list(c.nodes[0].wal)[n0:])
-    assert c.nodes[0].store[cold_key] == 0
-    # the cold key is still usable afterwards
-    assert c.run(Txn("c", [(WRITE, cold_key, 7)], 0)) == [7]
-    assert c.nodes[0].store[cold_key] == 7
 
 
 def test_bucket_padding_preserves_results_and_gids():

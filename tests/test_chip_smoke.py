@@ -10,7 +10,6 @@ from repro.core.packets import SwitchConfig
 def test_one_chip_phases_pass_at_small_width(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "N_YCSB", 400)
     monkeypatch.setattr(chip_smoke, "N_SMALLBANK", 300)
-    monkeypatch.setattr(chip_smoke, "N_PALLAS", 100)
     dev = chip_smoke.run(chip_smoke.ONE_CHIP,
                          SwitchConfig(regs_per_stage=1024))
     assert dev["platform"] == "cpu"

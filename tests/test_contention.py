@@ -23,6 +23,7 @@ import pickle
 import numpy as np
 import pytest
 
+from conftest import force_engine
 from hypothesis_compat import given, settings, st
 from repro.core.hotset import build_hot_index
 from repro.core.packets import ADD, READ, SwitchConfig
@@ -388,14 +389,14 @@ DIFF_P = storms.StormParams(n_nodes=2, keys_per_node=60,
                             p_hot_txn=0.4)
 
 
-def _diff_batch(n_nodes, async_hot, mode):
+def _diff_batch(n_nodes, async_hot):
     p = storms.StormParams(**{**DIFF_P.__dict__, "n_nodes": n_nodes})
     txns = storms.ycsb_a_storm(np.random.default_rng(9), 50, p)
     hi = build_hot_index([[(k, ADD)] for k in storms.hot_keys(p)], 16, BSW)
     outs = []
     for ea in (False, True):
-        c = Cluster(n_nodes, BSW, hi, use_switch=True, switch_mode=mode,
-                    async_hot=async_hot, early_abort=ea)
+        c = Cluster(n_nodes, BSW, hi, use_switch=True, async_hot=async_hot,
+                    early_abort=ea)
         for k in storms.hot_keys(p):
             c.load(k, 10)
         c.snapshot_offload()
@@ -420,7 +421,7 @@ def _diff_batch(n_nodes, async_hot, mode):
 @pytest.mark.parametrize("n_nodes", [1, 2])
 @pytest.mark.parametrize("async_hot", [False, True])
 def test_run_batch_early_abort_differential(n_nodes, async_hot):
-    _diff_batch(n_nodes, async_hot, "auto")
+    _diff_batch(n_nodes, async_hot)
 
 
 @pytest.mark.slow
@@ -428,8 +429,9 @@ def test_run_batch_early_abort_differential(n_nodes, async_hot):
 @pytest.mark.parametrize("n_nodes", [1, 2])
 @pytest.mark.parametrize("async_hot", [False, True])
 def test_run_batch_early_abort_differential_modes(mode, n_nodes,
-                                                  async_hot):
-    _diff_batch(n_nodes, async_hot, mode)
+                                                  async_hot, monkeypatch):
+    force_engine(monkeypatch, mode)
+    _diff_batch(n_nodes, async_hot)
 
 
 # ===================================================================== #

@@ -22,6 +22,7 @@ import copy
 import numpy as np
 import pytest
 
+from conftest import force_engine
 from hypothesis_compat import given, settings, st
 from repro.core.hotset import build_hot_index
 from repro.core.packets import ADD, READ, SwitchConfig
@@ -298,15 +299,16 @@ def test_numpy_payloads_take_no_template():
                                   pytest.param("staged",
                                                marks=pytest.mark.slow)])
 @pytest.mark.parametrize("async_hot", [False, True])
-def test_segmented_wal_identity_with_legacy_list(mode, async_hot):
+def test_segmented_wal_identity_with_legacy_list(mode, async_hot,
+                                                 monkeypatch):
     """The segmented WAL behind the ``log()`` API is observationally
     identical to the PR 5 in-memory list: same results, same registers,
     same stats, same (kind, tid, payload) record stream, same recovery."""
+    force_engine(monkeypatch, mode)
     txns = _txns(7, 60)
     outs = {}
     for wal_mode in ("segmented", "list"):
-        c = Cluster(2, SW, HI, switch_mode=mode, async_hot=async_hot,
-                    wal_mode=wal_mode)
+        c = Cluster(2, SW, HI, async_hot=async_hot, wal_mode=wal_mode)
         for k in KEYS:
             c.load(k, 5)
         c.snapshot_offload()

@@ -91,18 +91,6 @@ def test_unsafe_addp_dispatches_serial():
         SwitchEngine(CFG, regs0).execute(p, mode="staged")
 
 
-def test_pallas_equals_serial():
-    rng = np.random.default_rng(3)
-    p = random_batch(rng, 48, CFG.max_instrs, ops=(NOP, CADD))
-    regs0 = rng.integers(0, 100, (CFG.n_stages, CFG.regs_per_stage))
-    e1, e2 = SwitchEngine(CFG, regs0), SwitchEngine(CFG, regs0)
-    r1, ok1, _ = e1.execute(p, mode="serial")
-    r2, ok2, _ = e2.execute(p, mode="pallas")
-    np.testing.assert_array_equal(r1, r2)
-    np.testing.assert_array_equal(ok1, ok2)
-    np.testing.assert_array_equal(e1.read_all(), e2.read_all())
-
-
 def test_batch_order_is_serial_order():
     """Two conflicting txns: second must observe the first (pipeline
     no-reorder property, paper §5.1)."""
@@ -254,6 +242,70 @@ def test_serial_equals_loop_reference(case):
     np.testing.assert_array_equal(res, want_res)
     np.testing.assert_array_equal(ok, want_ok)
     np.testing.assert_array_equal(e.read_all(), want_regs)
+
+
+@pytest.mark.parametrize("S,R,B,K", [
+    (4, 8, 16, 3),
+    (6, 32, 64, 5),
+    (12, 64, 100, 8),          # B pads to the 128 bucket
+    (6, 32, 37, 5),
+    (4, 16, 1, 7),             # B=1 per-txn shape, odd K
+])
+def test_serial_shapes_equal_loop_reference(S, R, B, K):
+    """The serial engine across plane and batch shapes: random NOP / READ
+    / WRITE / ADD / CADD streams over the whole plane."""
+    rng = np.random.default_rng(S * 1000 + B)
+    cfg = SwitchConfig(n_stages=S, regs_per_stage=R, max_instrs=K)
+    p = empty_packets(B, cfg)
+    p["op"] = rng.integers(NOP, CADD + 1, (B, K)).astype(np.int32)
+    p["stage"] = rng.integers(0, S, (B, K)).astype(np.int32)
+    p["reg"] = rng.integers(0, R, (B, K)).astype(np.int32)
+    p["operand"] = rng.integers(-30, 30, (B, K)).astype(np.int32)
+    regs0 = rng.integers(-50, 100, (S, R))
+    want_res, want_ok, want_regs = serial_loop_ref(regs0, p)
+    e = SwitchEngine(cfg, regs0)
+    res, ok, _ = e.execute(p, mode="serial")
+    np.testing.assert_array_equal(res, want_res)
+    np.testing.assert_array_equal(ok, want_ok)
+    np.testing.assert_array_equal(e.read_all(), want_regs)
+
+
+AUTO_CASES = {
+    "affine": lambda rng: random_batch(rng, 24, CFG.max_instrs),
+    "staged": lambda rng: staged_addp_batch(rng, 24),
+    "serial_cadd": lambda rng: random_batch(rng, 24, CFG.max_instrs,
+                                            ops=(NOP, READ, WRITE, ADD,
+                                                 CADD)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AUTO_CASES))
+def test_auto_picks_the_engine_from_the_batch(case):
+    """``auto`` picks the engine from the batch's opcodes: READ / WRITE /
+    ADD only -> affine, safe ADDP -> staged, CADD -> serial; the results
+    are the serial semantics either way (multipass ADDP -> serial is
+    ``test_unsafe_addp_dispatches_serial``)."""
+    rng = np.random.default_rng(sorted(AUTO_CASES).index(case))
+    p = AUTO_CASES[case](rng)
+    regs0 = rng.integers(0, 50, (CFG.n_stages, CFG.regs_per_stage))
+    want_res, want_ok, want_regs = serial_loop_ref(regs0, p)
+    e = SwitchEngine(CFG, regs0)
+    pb = e.execute_batch(p)
+    assert pb.mode == case.split("_")[0]
+    np.testing.assert_array_equal(pb.results_np(), want_res)
+    np.testing.assert_array_equal(pb.ok_np(), want_ok)
+    np.testing.assert_array_equal(e.read_all(), want_regs)
+
+
+@pytest.mark.parametrize("mode", ["pallas", "bogus"])
+def test_engine_rejects_an_unknown_mode(mode):
+    """The engines are ``serial``, ``staged`` and ``affine``, or ``auto``
+    to pick one from the batch; any other name is refused before a
+    dispatch."""
+    e = SwitchEngine(CFG)
+    with pytest.raises(ValueError):
+        e.execute(random_batch(np.random.default_rng(0), 4, 2), mode=mode)
+    assert e.dispatch_count == 0 and e.next_gid == 0
 
 
 def test_serial_row_blocks_equal_loop_reference(monkeypatch):

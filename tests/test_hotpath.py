@@ -21,14 +21,16 @@ from repro.db.dbms import Cluster, LazyResults
 from repro.db.migrate import EpochController
 from repro.db.txn import Txn, key_of
 
+from conftest import force_engine
+
 SW = TB.SW
 
 
-def _make_pair(hi, loads, n_nodes, mode, max_inflight=2):
+def _make_pair(hi, loads, n_nodes, max_inflight=2):
     """(sync, async) cluster twins over the same placement and loads."""
-    cs = Cluster(n_nodes, SW, hi, use_switch=True, switch_mode=mode)
-    ca = Cluster(n_nodes, SW, hi, use_switch=True, switch_mode=mode,
-                 async_hot=True, max_inflight=max_inflight)
+    cs = Cluster(n_nodes, SW, hi, use_switch=True)
+    ca = Cluster(n_nodes, SW, hi, use_switch=True, async_hot=True,
+                 max_inflight=max_inflight)
     for c in (cs, ca):
         for k, v in loads:
             c.load(k, v)
@@ -36,9 +38,9 @@ def _make_pair(hi, loads, n_nodes, mode, max_inflight=2):
     return cs, ca
 
 
-def _assert_async_equals_sync(txns, hi, loads, n_nodes=4, mode="auto",
-                              batch_size=64, max_inflight=2):
-    cs, ca = _make_pair(hi, loads, n_nodes, mode, max_inflight)
+def _assert_async_equals_sync(txns, hi, loads, n_nodes=4, batch_size=64,
+                              max_inflight=2):
+    cs, ca = _make_pair(hi, loads, n_nodes, max_inflight)
     rs, ra = [], []
     for i in range(0, len(txns), batch_size):
         chunk = txns[i:i + batch_size]
@@ -69,20 +71,21 @@ def _assert_async_equals_sync(txns, hi, loads, n_nodes=4, mode="auto",
     return cs, ca
 
 
-@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged",
-                                  "pallas"])
-def test_async_equals_sync_ycsb(mode):
+@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged"])
+def test_async_equals_sync_ycsb(mode, monkeypatch):
+    force_engine(monkeypatch, mode)
     txns, hi, loads = TB._ycsb()
-    cs, _ = _assert_async_equals_sync(txns, hi, loads, mode=mode)
+    cs, _ = _assert_async_equals_sync(txns, hi, loads)
     assert cs.stats["hot"] > 0 and cs.stats["cold"] > 0
 
 
 @pytest.mark.parametrize("mode", ["auto", "serial"])
-def test_async_equals_sync_warm_and_multipass(mode):
+def test_async_equals_sync_warm_and_multipass(mode, monkeypatch):
     """Warm interleaves force mid-batch drains; random layout forces
-    multipass packets and (under auto) group splitting."""
+    multipass packets."""
+    force_engine(monkeypatch, mode)
     txns, hi, loads = TB._ycsb(top_k=40, layout_fn=random_layout)
-    cs, _ = _assert_async_equals_sync(txns, hi, loads, mode=mode)
+    cs, _ = _assert_async_equals_sync(txns, hi, loads)
     assert cs.stats["warm"] > 0 and cs.stats["multipass"] > 0
 
 
@@ -112,7 +115,7 @@ def _all_hot(hi_txns=96):
 
 def test_lazy_results_defer_materialization():
     hot, hi, loads = _all_hot()
-    _, ca = _make_pair(hi, loads, 4, "auto", max_inflight=8)
+    _, ca = _make_pair(hi, loads, 4, max_inflight=8)
     res = ca.run_batch(hot)
     # dispatched (commit-on-send: sends logged, commits counted) ...
     assert ca.stats["commits"] == len(hot)
@@ -133,7 +136,7 @@ def test_inflight_window_bounded():
     """Double buffering: at most max_inflight undrained handles exist;
     older groups are drained as newer ones are dispatched."""
     hot, hi, loads = _all_hot()
-    _, ca = _make_pair(hi, loads, 4, "auto", max_inflight=2)
+    _, ca = _make_pair(hi, loads, 4, max_inflight=2)
     for i in range(0, len(hot), 16):
         ca.run_batch(hot[i:i + 16])
         assert len(ca._inflight) <= 2
@@ -147,7 +150,7 @@ def test_warm_txn_is_a_drain_point():
     send.  Warm txns are identifiable in the WAL as the tids that log
     both a switch_send AND a commit (their 2PC'd cold part)."""
     txns, hi, loads = TB._ycsb(top_k=40, layout_fn=random_layout)
-    _, ca = _make_pair(hi, loads, 4, "auto", max_inflight=8)
+    _, ca = _make_pair(hi, loads, 4, max_inflight=8)
     list(ca.run_batch(txns))
     assert ca.stats["warm"] > 0
     warm_checked = 0
@@ -171,7 +174,7 @@ def test_drain_ordering_deterministic():
     txns, hi, loads = TB._ycsb(top_k=40, layout_fn=random_layout, n=160)
     walseqs = []
     for _ in range(2):
-        _, ca = _make_pair(hi, loads, 4, "auto", max_inflight=3)
+        _, ca = _make_pair(hi, loads, 4, max_inflight=3)
         res = ca.run_batch([copy.deepcopy(t) for t in txns])
         list(res)                                   # drain
         walseqs.append([(n.id, e.kind, e.tid, e.payload.get("gid"),
@@ -210,7 +213,7 @@ def test_migration_crosses_undrained_batch():
     in flight; migrate() drains it, evicts the post-group register values
     and recovery stays exact — identical to the sync world."""
     hi, txns, loads, Bk = _drift_setup()
-    cs, ca = _make_pair(hi, loads, 1, "auto", max_inflight=8)
+    cs, ca = _make_pair(hi, loads, 1, max_inflight=8)
     ctl_s = _attach_controller(cs, interval=25)
     ctl_a = _attach_controller(ca, interval=25)
     rs = cs.run_batch([copy.deepcopy(t) for t in txns])

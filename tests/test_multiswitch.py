@@ -30,6 +30,8 @@ from repro.db.dbms import Cluster
 from repro.db.migrate import EpochController
 from repro.db.txn import Txn, key_of, node_of
 
+from conftest import force_engine
+
 S, R, M = 4, 32, 8
 
 
@@ -86,8 +88,7 @@ def _drain(engine, pkts, meta, mode):
 #  N = 1: the sharded facade IS the single switch                       #
 # ===================================================================== #
 
-@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged",
-                                  "pallas"])
+@pytest.mark.parametrize("mode", ["auto", "serial", "affine", "staged"])
 def test_n1_facade_byte_identical(mode):
     rng = np.random.default_rng(3)
     keys = [key_of(0, i) for i in range(24)]
@@ -143,10 +144,7 @@ def test_sharded_matches_oracle_mixed(n, mode):
     _assert_matches_oracle(n, txns, mode)
 
 
-@pytest.mark.parametrize("mode", ["affine",
-                                  pytest.param("staged",
-                                               marks=pytest.mark.slow),
-                                  "pallas"])
+@pytest.mark.parametrize("mode", ["affine", "staged"])
 def test_sharded_matches_oracle_safe_modes(mode):
     rng = np.random.default_rng(5)
     keys = [key_of(0, i) for i in range(32)]
@@ -244,11 +242,10 @@ def _workload(n_hot=40, n_txns=120, seed=7):
     return txns, traces, hot
 
 
-def _cluster(n, traces, hot, mode, async_hot):
+def _cluster(n, traces, hot, async_hot):
     cfg = CFG(n)
     hi = build_hot_index(traces, len(hot), cfg)
-    c = Cluster(N_NODES, cfg, hi, use_switch=True, switch_mode=mode,
-                async_hot=async_hot)
+    c = Cluster(N_NODES, cfg, hi, use_switch=True, async_hot=async_hot)
     for k in hot:
         c.load(k, 100)
     c.snapshot_offload()
@@ -261,11 +258,13 @@ def _wal_stream(c):
 
 @pytest.mark.parametrize("async_hot", [False, True])
 @pytest.mark.parametrize("mode", ["auto", "serial"])
-def test_cluster_equivalent_across_shard_counts(mode, async_hot):
+def test_cluster_equivalent_across_shard_counts(mode, async_hot,
+                                                monkeypatch):
+    force_engine(monkeypatch, mode)
     txns, traces, hot = _workload()
     worlds = {}
     for n in (1, 2, 4):
-        c = _cluster(n, traces, hot, mode, async_hot)
+        c = _cluster(n, traces, hot, async_hot)
         res = []
         for i in range(0, len(txns), 32):
             res += c.run_batch([copy.deepcopy(t)
@@ -286,14 +285,12 @@ def test_cluster_equivalent_across_shard_counts(mode, async_hot):
         assert _wal_stream(c1) == _wal_stream(cn)
 
 
-@pytest.mark.parametrize("mode", ["affine",
-                                  pytest.param("staged",
-                                               marks=pytest.mark.slow),
-                                  "pallas"])
-def test_cluster_explicit_modes_match_across_shards(mode):
+@pytest.mark.parametrize("mode", ["affine", "staged"])
+def test_cluster_explicit_modes_match_across_shards(mode, monkeypatch):
+    force_engine(monkeypatch, mode)
     txns, traces, hot = _workload(n_txns=60, seed=13)
-    c1 = _cluster(1, traces, hot, mode, False)
-    c2 = _cluster(2, traces, hot, mode, False)
+    c1 = _cluster(1, traces, hot, False)
+    c2 = _cluster(2, traces, hot, False)
     r1 = c1.run_batch([copy.deepcopy(t) for t in txns])
     r2 = c2.run_batch([copy.deepcopy(t) for t in txns])
     assert r1 == r2
@@ -306,7 +303,7 @@ def test_cluster_recovery_at_n2():
     """Crash/recover of the sharded plane: WAL replay onto the [N, S, R]
     register file reproduces the pre-crash state exactly."""
     txns, traces, hot = _workload(n_txns=80, seed=17)
-    c = _cluster(2, traces, hot, "auto", False)
+    c = _cluster(2, traces, hot, False)
     c.run_batch([copy.deepcopy(t) for t in txns])
     before = np.asarray(c.switch.read_all()).copy()
     c.crash_switch_and_recover()

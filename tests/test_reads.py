@@ -6,7 +6,7 @@ differential harness against a plain-dict oracle (tests/oracle.py).
 Pins:
   * randomized mixed read/write/scan streams are byte-identical to the
     oracle across engine modes x sync/async x N in {1, 2, 4} switches
-    (tier-1 runs the auto/pallas corner; the full matrix is @slow);
+    (tier-1 runs the auto/serial corner; the full matrix is @slow);
   * reads on an async cluster need NO drain: the FIFO dispatch thread
     orders the gather after every in-flight write group while their
     result planes stay device-resident (``_inflight`` untouched);
@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from hypothesis_compat import given, settings, st
+from conftest import force_engine
 from oracle import OracleDB
 from repro.core.hotset import build_hot_index
 from repro.core.packets import (ADD, CADD, READ, WRITE, SwitchConfig,
@@ -37,7 +38,7 @@ from repro.db.txn import Txn, key_of, node_of
 
 S, R, MI = 4, 32, 8
 N_NODES = 4
-MODES = ["auto", "serial", "affine", "staged", "pallas"]
+MODES = ["auto", "serial", "affine", "staged"]
 
 
 def CFG(n=1):
@@ -45,16 +46,15 @@ def CFG(n=1):
                         n_switches=n)
 
 
-def _fixture(n_switches=1, async_hot=False, mode="auto", seed=0,
-             n_hot_per_node=12, **kw):
+def _fixture(n_switches=1, async_hot=False, seed=0, n_hot_per_node=12,
+             **kw):
     """(cluster, oracle, hot_keys, cold_keys) twins over one placement."""
     cfg = CFG(n_switches)
     hot = [key_of(nd, i) for nd in range(N_NODES)
            for i in range(n_hot_per_node)]
     hi = build_hot_index([[(k, "W")] for k in hot], len(hot), cfg)
     assert set(hi.placement.slot) == set(hot)
-    c = Cluster(N_NODES, cfg, hi, async_hot=async_hot, switch_mode=mode,
-                **kw)
+    c = Cluster(N_NODES, cfg, hi, async_hot=async_hot, **kw)
     o = OracleDB()
     cold = [key_of(nd, 500 + i) for nd in range(N_NODES) for i in range(6)]
     rng = np.random.default_rng(seed)
@@ -134,11 +134,12 @@ def _differential_stream(c, o, hot, cold, seed=1, n_steps=24,
 
 @pytest.mark.parametrize("async_hot", [False, True])
 @pytest.mark.parametrize("n_switches", [1, 2])
-@pytest.mark.parametrize("mode", ["auto", "pallas"])
-def test_mixed_stream_matches_oracle(n_switches, async_hot, mode):
-    c, o, hot, cold = _fixture(n_switches, async_hot, mode)
-    # explicit modes reject some op shapes; auto keeps CADD in the mix
-    _differential_stream(c, o, hot, cold, allow_cadd=(mode == "auto"))
+@pytest.mark.parametrize("mode", ["auto", "serial"])
+def test_mixed_stream_matches_oracle(n_switches, async_hot, mode,
+                                     monkeypatch):
+    force_engine(monkeypatch, mode)
+    c, o, hot, cold = _fixture(n_switches, async_hot)
+    _differential_stream(c, o, hot, cold)
 
 
 @pytest.mark.slow
@@ -146,10 +147,12 @@ def test_mixed_stream_matches_oracle(n_switches, async_hot, mode):
 @pytest.mark.parametrize("n_switches", [1, 2, 4])
 @pytest.mark.parametrize("mode", MODES)
 def test_mixed_stream_matches_oracle_full_matrix(n_switches, async_hot,
-                                                 mode):
-    c, o, hot, cold = _fixture(n_switches, async_hot, mode, seed=2)
+                                                 mode, monkeypatch):
+    force_engine(monkeypatch, mode)
+    c, o, hot, cold = _fixture(n_switches, async_hot, seed=2)
+    # affine and staged reject CADD; auto and serial keep it in the mix
     _differential_stream(c, o, hot, cold, seed=3, n_steps=32,
-                         allow_cadd=(mode == "auto"))
+                         allow_cadd=mode in ("auto", "serial"))
 
 
 def test_async_reads_do_not_drain_inflight_writes():

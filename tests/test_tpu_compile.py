@@ -4,7 +4,8 @@ Every device program of the P4DB switch plane is lowered and compiled by
 the TPU compiler at the default switch width (20 stages x 65,536 int32
 registers, a 5 MiB register file), batch B = 256, K = 8 instructions:
 
-  * the four ``kernels/switch_txn`` Pallas kernels with ``interpret=False``
+  * the three ``kernels/switch_txn`` gather and scan programs with
+    ``interpret=False``
     (Mosaic refuses what interpret mode accepts: scalar VMEM stores, more
     VMEM or SMEM than a core has);
   * the three fused jit engines (``serial`` / ``affine`` / ``staged``) the
@@ -71,8 +72,6 @@ def _kernel_args(name, dev):
     regs = _spec((S, R), dev)
     plane = _spec((B, K), dev)
     scalar = _spec((), dev)
-    if name == "switch_exec":
-        return (regs, plane, plane, plane, plane), {}
     if name == "gather_results":
         return (plane, _spec((M,), dev)), {}
     if name == "scan_prune":
@@ -81,8 +80,8 @@ def _kernel_args(name, dev):
     return (regs, _spec((SCAN_M,), dev), scalar, scalar), dict(k=64)
 
 
-@pytest.mark.parametrize("name", ["switch_exec", "gather_results",
-                                  "scan_prune", "scan_topk"])
+@pytest.mark.parametrize("name", ["gather_results", "scan_prune",
+                                  "scan_topk"])
 def test_switch_txn_kernel_compiles(chip, name):
     args, static = _kernel_args(name, chip)
     compiled = getattr(ktx, name).lower(*args, interpret=False,
